@@ -27,6 +27,7 @@ import jax
 import numpy as np
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.utils import splitmix32
 
 _CHK_SEED = jnp.uint32(0x9E3779B9)
@@ -60,35 +61,23 @@ class CountingSet:
     one-hot in one pass — the fold-side twin of the mesh pipeline) — the
     TPU-native scatter idiom, bitwise-identical to the scatter path
     (integer adds; idempotent commutative max). ``"auto"`` (default) picks
-    Pallas on a real TPU backend and falls back to scatter elsewhere, so
-    CPU test runs are unchanged."""
+    the compiled kernel on a TPU backend and the scatter elsewhere, so CPU
+    test runs are unchanged; ``"pallas"`` off a TPU runs the kernel in
+    interpret mode (:func:`repro.kernels.compiled` is the gate)."""
 
     capacity: int
     n_key_cols: int
     backend: str = "auto"           # "auto" | "pallas" | "scatter"
-    pallas_interpret: bool | None = None  # None: compiled on real TPU,
-    #                                       interpret elsewhere (CPU runs)
 
     def __post_init__(self):
         if self.backend not in ("auto", "pallas", "scatter"):
             raise ValueError(f"unknown CountingSet backend {self.backend!r}")
 
-    def _use_pallas(self) -> bool:
+    def uses_pallas(self) -> bool:
+        """Whether :meth:`increment` runs the fused Pallas fold."""
         if self.backend == "auto":
-            return jax.default_backend() == "tpu"
+            return kernels.compiled()
         return self.backend == "pallas"
-
-    def _interpret(self) -> bool:
-        if self.pallas_interpret is None:
-            return jax.default_backend() != "tpu"
-        return self.pallas_interpret
-
-    def _cap_tile(self) -> int:
-        # largest tile ≤ 512 dividing capacity (hist kernel grid constraint)
-        ct = min(512, self.capacity)
-        while self.capacity % ct:
-            ct -= 1
-        return max(1, ct)
 
     def init(self):
         cap, k = self.capacity, self.n_key_cols
@@ -109,7 +98,7 @@ class CountingSet:
         keys_u = keys.astype(jnp.uint32) ^ jnp.uint32(_SIGN)
         row = jnp.concatenate([keys_u, chk[:, None], (~chk)[:, None]], axis=-1)
         row = jnp.where(valid[:, None], row, jnp.uint32(0))
-        if self._use_pallas():
+        if self.uses_pallas():
             from repro.kernels.fold_scatter.ops import fold_count_max
 
             # OOB slots are dropped by the kernel — mask invalid to -1
@@ -120,8 +109,7 @@ class CountingSet:
             # .at[].add / .at[].max — integer adds commute, max is
             # idempotent and commutative
             d_count, d_packed = fold_count_max(
-                mslot, amt, row, cap,
-                cap_tile=self._cap_tile(), interpret=self._interpret())
+                mslot, amt, row, cap, interpret=not kernels.compiled())
             count = state["count"] + d_count
             packed = jnp.maximum(state["packed"], d_packed)
         else:

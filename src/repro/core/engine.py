@@ -23,8 +23,9 @@ folds the survey callback with all six metadata items local (Sec. 4.2/4.3).
 
 Pull superstep: shard s requests `Adj₊ᵐ(q)` once per (shard, q) for targets
 whose row is cheaper to move than the wedge candidates (the paper's
-per-pair decision), receives padded rows, intersects its local suffixes
-against them (``kernels/intersect``) and folds the survey locally.
+per-pair decision), receives padded rows, searches each local suffix
+wedge's key in the pulled row (the push lane's keyed lower bound, tiled
+by wedge rank within a staging budget) and folds the survey locally.
 
 Hub superstep (two-tier exchange, after Arifuzzaman et al.'s heavy-vertex
 split): wedges whose center q has degree ≥ the plan's ``hub_theta`` never
@@ -75,6 +76,7 @@ import numpy as np
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import kernels
 from repro.comm.exchange import Exchange, make_exchange
 from repro.core.dodgr import ShardedDODGr, meta_widths
 from repro.core.surveys import (MetaSpec, Survey, TriangleBatch, expand_lanes,
@@ -101,15 +103,10 @@ class EngineConfig:
     n_pull_steps: int = 0
     cost_model: str = "entries"   # "entries" (paper-faithful) | "bytes"
     unroll_steps: bool = False    # unroll superstep scans (cost-analysis mode)
-    use_pallas: bool = False      # route search/intersect through Pallas kernels
-    pallas_interpret: bool = True  # interpret mode (CPU container validation)
-    pull_kernel: str = "auto"     # pull-phase Pallas kernel choice (only read
-    #                               when use_pallas): "auto"/"fused" runs the
-    #                               one-residency kernels/wedge_intersect
-    #                               (candidate keys gathered in VMEM);
-    #                               "split" keeps the historic two-launch
-    #                               gather + kernels/intersect composition.
-    #                               All three are bitwise-identical
+    use_pallas: bool = False      # run both lanes' keyed searches through the
+    #                               kernels/wedge_check Pallas kernel (compiled
+    #                               on a TPU, interpreted elsewhere:
+    #                               kernels.compiled)
     shard_axis: str | None = None  # mesh axis name for sharding constraints
     sample_p: float = 1.0         # DOULION edge-keep probability the graph was
     #                               sparsified with (host-side); < 1 debiases
@@ -322,7 +319,9 @@ def _answer_push_queries(gr: ShardedDODGr, qr, cfg: EngineConfig,
     to fold form; owner-local items (meta(q)/(r)/(qr)) are gathered at
     declared width only — unread items skip the gather."""
     S, e_cap, n_loc = gr.S, gr.e_cap, gr.n_loc
-    n_steps = max(1, int(np.ceil(np.log2(max(2, e_cap)))) + 1)
+    # the search spans one Adj₊(q) row, at most d_plus_max slots — a
+    # binary search over the whole [e_cap] array would only add probes
+    n_steps = max(1, int(np.ceil(np.log2(max(2, gr.d_plus_max)))) + 1)
     vq_i = narrow_lanes(gr.vmeta_i, spec.vq_i)
     vq_f = narrow_lanes(gr.vmeta_f, spec.vq_f)
     vr_i = narrow_lanes(gr.tmeta_i, spec.vr_i)
@@ -340,7 +339,7 @@ def _answer_push_queries(gr: ShardedDODGr, qr, cfg: EngineConfig,
         hi = row_ptr[lq + 1]
         if cfg.use_pallas:
             pos = wc_ops.wedge_check(nbr_d, nbr_h, nbr, lo, hi, q["rd"], q["rh"],
-                                     q["r"], interpret=cfg.pallas_interpret)
+                                     q["r"], interpret=not kernels.compiled())
         else:
             pos = _lower_bound(nbr_d, nbr_h, nbr, lo, hi, q["rd"], q["rh"],
                                q["r"], n_steps)
@@ -630,17 +629,53 @@ def _pull_wire(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
     return rep, req["ok"].sum(dtype=jnp.float32)
 
 
+# Requester-side staging budget of one pull superstep, per device. The
+# requester closes each pulled edge's suffix wedges against the pulled
+# row; built at once as [pull_edge_cap, d_plus_max] candidate blocks per
+# (shard, dest), the stacked batch outgrew HBM at R-MAT scale 14 (and
+# padded every edge to the longest suffix). The window's wedges are
+# instead enumerated by rank and built and folded in tiles whose staged
+# entries fit this budget — the device-memory twin of the planner's
+# ~4 MiB reply-window bound (pushpull._autotune_pull_q_cap).
+PULL_STAGE_BYTES = 64 << 20
+# int32-sized words XLA keeps live per staged wedge besides its metadata
+# lanes (ranks, edge and row indices, search keys, bounds and probes,
+# hit masks, the p/q/r ids), sized against compiled memory_analysis() on
+# TPU v5e (tests/test_tpu_compile.py holds a program to the budget)
+_PULL_STAGE_WORDS = 64
+
+
+def pull_tile_wedges(S_ax: int, meta_words: int, window_max: int) -> int:
+    """Wedge slots per staged pull tile for ``S_ax`` stacked shards whose
+    batch carries ``meta_words`` metadata words per triangle — never more
+    than the ``window_max`` wedges one window can hold."""
+    return max(1, min(window_max, PULL_STAGE_BYTES
+                      // (4 * S_ax * (_PULL_STAGE_WORDS + meta_words))))
+
+
 def _pull_compute(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
-                  spec: MetaSpec, exch: Exchange, rep):
-    """The fold half of one pull superstep: intersect local suffixes
-    against the pulled rows ``rep`` (from :func:`_pull_wire` at the same
-    ``t``) and emit the TriangleBatch. Purely device-local — no
-    collectives — so the mesh pipeline can overlap it with the next
-    superstep's wire."""
+                  spec: MetaSpec, exch: Exchange, rep, survey: Survey,
+                  state):
+    """The fold half of one pull superstep: close the local suffix wedges
+    of this window's pulled edges against the pulled rows ``rep`` (from
+    :func:`_pull_wire` at the same ``t``) and fold the triangles into
+    ``state``. Purely device-local — no collectives — so the mesh pipeline
+    can overlap it with the next superstep's wire.
+
+    The window's requester rows (one per (dest, edge slot), in that order)
+    are weighted by their suffix wedge counts; wedge rank ``k`` of the
+    inclusive cumsum addresses its edge and suffix position exactly as the
+    push lane addresses its streams. Wedges are built and folded in tiles
+    of :func:`pull_tile_wedges` ranks — as many tiles as the busiest shard
+    needs — so the staged batch stays within :data:`PULL_STAGE_BYTES`
+    and no slot is spent on an empty suffix position. Ranks follow the
+    (dest, edge, suffix) order, so every survey sees its triangles in one
+    fixed sequence. Returns ``(state, tris, checked, overflow)``, the
+    counts per shard."""
     S, e_cap, n_loc = gr.S, gr.e_cap, gr.n_loc
+    S_ax = gr.row_ptr.shape[0]
     ecap = cfg.pull_edge_cap
-    L = gr.d_plus_max
-    Lr = cfg.pull_row_cap if cfg.pull_row_cap else L
+    Lr = cfg.pull_row_cap if cfg.pull_row_cap else gr.d_plus_max
     n_steps = max(1, int(np.ceil(np.log2(max(2, Lr)))) + 1)
     out_cap = exch.out_cap
 
@@ -651,154 +686,117 @@ def _pull_compute(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
     epq_f_l = narrow_lanes(gr.emeta_f, spec.e_pq_f)
     epr_i_l = narrow_lanes(gr.emeta_i, spec.e_pr_i)
     epr_f_l = narrow_lanes(gr.emeta_f, spec.e_pr_f)
+    meta_words = sum(x.shape[-1] for x in (
+        vp_i_l, vp_f_l, epq_i_l, epq_f_l, epr_i_l, epr_f_l, rep["vq_i"],
+        rep["vq_f"], rep["r_ti"], rep["r_tf"], rep["r_ei"], rep["r_ef"]))
+    # a suffix holds at most d_plus_max - 1 wedges
+    T = pull_tile_wedges(S_ax, meta_words,
+                         S * ecap * max(1, gr.d_plus_max - 1))
+    # reply rows flattened [out_cap·Lr]: row ``ridx`` spans
+    # [ridx·Lr, ridx·Lr + ln)
+    flat = lambda x: x.reshape((S_ax, out_cap * Lr) + x.shape[3:])
+    rows = {k: flat(rep[k]) for k in ("r_nbr", "r_d", "r_h", "r_ti", "r_tf",
+                                      "r_ei", "r_ef")}
+    if cfg.delta:
+        rows["r_new"] = flat(rep["r_new"])
 
     # jnp (not np) coercion: a mesh local view hands traced map rows
     pcap_d = jnp.asarray(exch.caps, jnp.int32)              # [S, S]
     boff = jnp.asarray(exch.block_off)                      # [S, S]
 
-    # --- requester: intersect local suffixes against pulled rows ---
-    if cfg.use_pallas and cfg.pull_kernel in ("auto", "fused"):
-        from repro.kernels.wedge_intersect import ops as wi_ops
-    elif cfg.use_pallas:
-        from repro.kernels.intersect import ops as is_ops
+    if cfg.use_pallas:
+        from repro.kernels.wedge_check import ops as wc_ops
 
-    def intersect(qrank2, qbase, qcount, pulled_end, dest_start2, ord2, pull,
-                  row_ptr, edge_src, nbr, nbr_d, nbr_h, nbr_new, gen,
-                  epq_i, epq_f, epr_i, epr_f, vp_i, vp_f, pcap_d, boff, rp):
-        d = jnp.arange(S, dtype=jnp.int32)
+    def window(qrank2, qbase, qcount, pulled_end, dest_start2, ord2, pull,
+               gen, row_ptr, edge_src, pcap_d, boff):
+        """Superstep t's requester rows: the pulled edge, its reply row and
+        its suffix wedge count, with the inclusive wedge cumsum."""
         lo_rank = qbase + t * pcap_d
         hi_rank = qbase + jnp.minimum((t + 1) * pcap_d, qcount)
         estart = jnp.searchsorted(qrank2, lo_rank, side="left").astype(jnp.int32)
         eend = jnp.searchsorted(qrank2, hi_rank, side="left").astype(jnp.int32)
         estart = jnp.clip(estart, dest_start2, pulled_end)
         eend = jnp.clip(eend, dest_start2, pulled_end)
-        c2 = jnp.arange(ecap, dtype=jnp.int32)
-        j = estart[:, None] + c2[None, :]                  # [S, ecap] ord2 idx
-        ok_e = (j < eend[:, None])
         overflow = jnp.maximum(eend - estart - ecap, 0).sum()
+        row = jnp.arange(S * ecap, dtype=jnp.int32)
+        d = row // ecap                                    # dest of the row
+        j = estart[d] + row % ecap                         # ord2 index
         j_c = jnp.clip(j, 0, e_cap - 1)
-        ok_e = ok_e & pull[ps_ord2 := ord2[j_c]]
-        e = ps_ord2                                        # original edge slot
+        e = ord2[j_c]                                      # original edge slot
+        ok = (j < eend[d]) & pull[e]
         if cfg.delta:
             # pulled edges outside the delta_gen mask cannot seed a new
             # triangle — skip their suffixes (keeps the wedges_pulled stat
             # equal to the planner's masked pulled_wedges accounting)
-            ok_e = ok_e & gen[e]
-        slot = jnp.clip(qrank2[j_c] - qbase[:, None] - t * pcap_d[:, None],
-                        0, jnp.maximum(pcap_d - 1, 0)[:, None])
-        ridx = jnp.clip(boff[:, None] + slot, 0, out_cap - 1)  # flat reply idx
-
-        # suffix candidates of edge e: [S, ecap, L]
+            ok = ok & gen[e]
         lp = jnp.clip(edge_src[e] // S, 0, n_loc - 1)
-        row_end = row_ptr[lp + 1]
-        k = jnp.arange(L, dtype=jnp.int32)
-        r_pos = jnp.clip(e[..., None] + 1 + k[None, None, :], 0, e_cap - 1)
-        cand_ok = ok_e[..., None] & (e[..., None] + 1 + k[None, None, :] < row_end[..., None])
+        w = jnp.where(ok, jnp.maximum(row_ptr[lp + 1] - e - 1, 0), 0)
+        slot = jnp.clip(qrank2[j_c] - qbase[d] - t * pcap_d[d],
+                        0, jnp.maximum(pcap_d[d] - 1, 0))
+        ridx = jnp.clip(boff[d] + slot, 0, out_cap - 1)   # flat reply row
+        return dict(e=e, ridx=ridx, cum=jnp.cumsum(w)), overflow
 
-        # pulled row for each edge slot: [S, ecap, Lr]
-        def pick(x):
-            return x[ridx]                                 # [S, ecap, ...]
+    win, overflow = jax.vmap(window)(
+        ps["qrank2"], ps["qbase"], ps["qcount"], ps["pulled_end"],
+        ps["dest_start2"], ps["ord2"], ps["pull"], gr.delta_gen, gr.row_ptr,
+        gr.edge_src, pcap_d, boff)
+    total = win["cum"][:, -1]                              # wedges per shard
 
-        rn, rd_, rh_ = pick(rp["r_nbr"]), pick(rp["r_d"]), pick(rp["r_h"])
-        ln = pick(rp["ln"])
-
-        if cfg.use_pallas and cfg.pull_kernel in ("auto", "fused"):
-            # fused wedge-addressing + intersection: the candidate keys are
-            # gathered from the VMEM-resident suffix arrays *inside* the
-            # kernel, so the [B, L] cd/ch staging arrays never materialize
-            # and the key arrays are read in one residency
-            pos, ci = wi_ops.wedge_intersect(
-                nbr_d, nbr_h, nbr, e.reshape(-1),
-                rd_.reshape(-1, Lr), rh_.reshape(-1, Lr), rn.reshape(-1, Lr),
-                ln.reshape(-1), L=L, interpret=cfg.pallas_interpret)
-            pos = pos.reshape(S, ecap, L)
-            ci = ci.reshape(S, ecap, L)
-        elif cfg.use_pallas:
-            cd = nbr_d[r_pos]
-            ch = nbr_h[r_pos]
-            ci = nbr[r_pos]
-            # the kernel co-blocks rows and candidates at one width: pad the
-            # Lr-wide reply rows back to L with the same sentinels the owner
-            # writes, reproducing the historic inputs bit for bit (padding
-            # is local — it never crossed the wire)
-            if Lr < L:
-                padw = ((0, 0), (0, 0), (0, L - Lr))
-                rd_p = jnp.pad(rd_, padw, constant_values=BIG_I32)
-                rh_p = jnp.pad(rh_, padw, constant_values=jnp.uint32(0xFFFFFFFF))
-                rn_p = jnp.pad(rn, padw, constant_values=BIG_I32)
-            else:
-                rd_p, rh_p, rn_p = rd_, rh_, rn
-            pos = is_ops.intersect(
-                rd_p.reshape(-1, L), rh_p.reshape(-1, L), rn_p.reshape(-1, L),
-                ln.reshape(-1), cd.reshape(-1, L), ch.reshape(-1, L),
-                ci.reshape(-1, L), interpret=cfg.pallas_interpret,
-            ).reshape(S, ecap, L)
+    def wedges(rank0, win, row_ptr, edge_src, nbr, nbr_d, nbr_h, nbr_new,
+               epq_i, epq_f, epr_i, epr_f, vp_i, vp_f, rp, rows):
+        """Tile of wedge ranks [rank0, rank0 + T): locate each wedge's
+        edge and suffix position, search its key in the pulled row."""
+        cum = win["cum"]
+        rank = rank0 + jnp.arange(T, dtype=jnp.int32)
+        ok = rank < cum[-1]
+        r = jnp.clip(jnp.searchsorted(cum, rank, side="right"),
+                     0, S * ecap - 1).astype(jnp.int32)
+        prev = jnp.where(r > 0, cum[jnp.maximum(r - 1, 0)], 0)
+        e = win["e"][r]
+        ridx = win["ridx"][r]
+        r_pos = jnp.clip(e + 1 + rank - prev, 0, e_cap - 1)
+        ci = nbr[r_pos]
+        lo = ridx * Lr
+        hi = lo + rp["ln"][ridx]
+        if cfg.use_pallas:
+            pos = wc_ops.wedge_check(rows["r_d"], rows["r_h"], rows["r_nbr"],
+                                     lo, hi, nbr_d[r_pos], nbr_h[r_pos], ci,
+                                     interpret=not kernels.compiled())
         else:
-            cd = nbr_d[r_pos]
-            ch = nbr_h[r_pos]
-            ci = nbr[r_pos]
-
-            def lb(rowd, rowh, rowi, ln_1, qd, qh, qi):
-                lo = jnp.zeros_like(qi)
-                hi = jnp.broadcast_to(ln_1, qi.shape)
-                return _lower_bound(rowd, rowh, rowi, lo, hi, qd, qh, qi, n_steps)
-
-            pos = jax.vmap(jax.vmap(lb))(rd_, rh_, rn, ln, cd, ch, ci)
-
-        pos_c = jnp.clip(pos, 0, Lr - 1)
+            pos = _lower_bound(rows["r_d"], rows["r_h"], rows["r_nbr"], lo,
+                               hi, nbr_d[r_pos], nbr_h[r_pos], ci, n_steps)
+        pos_c = jnp.clip(pos, 0, out_cap * Lr - 1)
         # the reply header's ok word (the owner's view of request validity)
         # rides back with the rows; AND-ing it in is a no-op on every slot
         # the requester's own maps admit, and keeps the planned header word
         # live on the wire
-        hit = (cand_ok & pick(rp["ok"])[..., None] & (pos < ln[..., None])
-               & (jnp.take_along_axis(rn, pos_c, -1) == ci))
+        hit = (ok & rp["ok"][ridx] & (pos < hi)
+               & (rows["r_nbr"][pos_c] == ci))
         if cfg.delta:
-            qr_new = jnp.take_along_axis(pick(rp["r_new"]), pos_c, -1)
-            hit &= (nbr_new[e][..., None] | nbr_new[r_pos] | qr_new)
-
-        def row_at(x):
-            return jnp.take_along_axis(pick(x), pos_c[..., None], 2)
-
-        B = S * ecap * L
-        flat = lambda x: x.reshape((B,) + x.shape[3:])
-        tri = TriangleBatch(
-            p=flat(jnp.broadcast_to(edge_src[e][..., None], (S, ecap, L))),
-            q=flat(jnp.broadcast_to(nbr[e][..., None], (S, ecap, L))),
-            r=flat(ci),
-            vp_i=flat(jnp.broadcast_to(vp_i[lp][:, :, None], (S, ecap, L, vp_i.shape[-1]))),
-            vq_i=flat(jnp.broadcast_to(pick(rp["vq_i"])[:, :, None], (S, ecap, L, rp["vq_i"].shape[-1]))),
-            vr_i=flat(row_at(rp["r_ti"])),
-            vp_f=flat(jnp.broadcast_to(vp_f[lp][:, :, None], (S, ecap, L, vp_f.shape[-1]))),
-            vq_f=flat(jnp.broadcast_to(pick(rp["vq_f"])[:, :, None], (S, ecap, L, rp["vq_f"].shape[-1]))),
-            vr_f=flat(row_at(rp["r_tf"])),
-            e_pq_i=flat(jnp.broadcast_to(epq_i[e][:, :, None], (S, ecap, L, epq_i.shape[-1]))),
-            e_pr_i=flat(epr_i[r_pos]),
-            e_qr_i=flat(row_at(rp["r_ei"])),
-            e_pq_f=flat(jnp.broadcast_to(epq_f[e][:, :, None], (S, ecap, L, epq_f.shape[-1]))),
-            e_pr_f=flat(epr_f[r_pos]),
-            e_qr_f=flat(row_at(rp["r_ef"])),
-            valid=flat(hit),
+            hit &= nbr_new[e] | nbr_new[r_pos] | rows["r_new"][pos_c]
+        lp = jnp.clip(edge_src[e] // S, 0, n_loc - 1)
+        return TriangleBatch(
+            p=edge_src[e], q=nbr[e], r=ci,
+            vp_i=vp_i[lp], vq_i=rp["vq_i"][ridx], vr_i=rows["r_ti"][pos_c],
+            vp_f=vp_f[lp], vq_f=rp["vq_f"][ridx], vr_f=rows["r_tf"][pos_c],
+            e_pq_i=epq_i[e], e_pr_i=epr_i[r_pos], e_qr_i=rows["r_ei"][pos_c],
+            e_pq_f=epq_f[e], e_pr_f=epr_f[r_pos], e_qr_f=rows["r_ef"][pos_c],
+            valid=hit,
         )
-        checked = cand_ok.sum(dtype=jnp.float32)
-        return tri, checked, overflow.astype(jnp.float32)
 
-    tri, checked, overflow = jax.vmap(intersect)(
-        ps["qrank2"], ps["qbase"], ps["qcount"], ps["pulled_end"],
-        ps["dest_start2"], ps["ord2"], ps["pull"], gr.row_ptr, gr.edge_src,
-        gr.nbr, gr.nbr_d, gr.nbr_h, gr.nbr_new, gr.delta_gen,
-        epq_i_l, epq_f_l, epr_i_l, epr_f_l, vp_i_l, vp_f_l, pcap_d, boff, rep)
-    return tri, checked, overflow
+    def tile(i, carry):
+        state, tris = carry
+        tri = jax.vmap(partial(wedges, i * T))(
+            win, gr.row_ptr, gr.edge_src, gr.nbr, gr.nbr_d, gr.nbr_h,
+            gr.nbr_new, epq_i_l, epq_f_l, epr_i_l, epr_f_l, vp_i_l, vp_f_l,
+            rep, rows)
+        state = jax.vmap(survey.update)(state, tri)
+        return state, tris + tri.valid.sum(axis=1, dtype=jnp.int32)
 
-
-def _pull_superstep(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
-                    spec: MetaSpec, exch: Exchange):
-    """One pull superstep: request rows, answer, intersect, emit
-    TriangleBatch — the sequential composition of :func:`_pull_wire` and
-    :func:`_pull_compute` (the stacked path; the mesh path interleaves
-    them across supersteps)."""
-    rep, n_req = _pull_wire(gr, ps, t, cfg, spec, exch)
-    tri, checked, overflow = _pull_compute(gr, ps, t, cfg, spec, exch, rep)
-    return tri, checked, overflow, n_req
+    n_tiles = (total.max() + (T - 1)) // T
+    state, tris = jax.lax.fori_loop(
+        0, n_tiles, tile, (state, jnp.zeros((S_ax,), jnp.int32)))
+    return state, tris, total, overflow
 
 
 # ---------------------------------------------------------------------------
@@ -978,14 +976,13 @@ def _survey_body(gr: ShardedDODGr, survey: Survey, cfg: EngineConfig,
         reply_step_words = float(pull_exch.round_slots() * (w_hdr + Lr * w_row))
 
         def pull_fold(state, stats, t, rep, n_req):
-            tri, checked, overflow = _pull_compute(
-                gr, ps, t, cfg, spec, pull_exch, rep)
-            state = jax.vmap(survey.update)(state, tri)
+            state, tris, checked, overflow = _pull_compute(
+                gr, ps, t, cfg, spec, pull_exch, rep, survey, state)
             stats = dict(stats)
-            stats["wedges_pulled"] += checked.sum()
-            stats["tris_pull"] += tri.valid.sum(dtype=jnp.float32)
+            stats["wedges_pulled"] += checked.sum(dtype=jnp.float32)
+            stats["tris_pull"] += tris.sum(dtype=jnp.float32)
             stats["pull_requests"] += n_req
-            stats["pull_overflow"] += overflow.sum()
+            stats["pull_overflow"] += overflow.sum(dtype=jnp.float32)
             stats["wire_req_words"] += req_step_words
             stats["wire_reply_words"] += reply_step_words
             return state, stats
@@ -1011,17 +1008,8 @@ def _survey_body(gr: ShardedDODGr, survey: Survey, cfg: EngineConfig,
         else:
             def pull_step(carry, t):
                 state, stats = carry
-                tri, checked, overflow, n_req = _pull_superstep(
-                    gr, ps, t, cfg, spec, pull_exch)
-                state = jax.vmap(survey.update)(state, tri)
-                stats = dict(stats)
-                stats["wedges_pulled"] += checked.sum()
-                stats["tris_pull"] += tri.valid.sum(dtype=jnp.float32)
-                stats["pull_requests"] += n_req
-                stats["pull_overflow"] += overflow.sum()
-                stats["wire_req_words"] += req_step_words
-                stats["wire_reply_words"] += reply_step_words
-                return (state, stats), None
+                rep, n_req = _pull_wire(gr, ps, t, cfg, spec, pull_exch)
+                return pull_fold(state, stats, t, rep, n_req), None
 
             (state, stats), _ = jax.lax.scan(
                 pull_step, (state, stats),
@@ -1069,8 +1057,6 @@ def make_survey_fn(survey: Survey, cfg: EngineConfig, mesh=None):
 
         return run
 
-    from jax.experimental.shard_map import shard_map
-
     from repro.core.dodgr import mesh_specs
 
     axis = mesh.axis_names[-1]
@@ -1101,8 +1087,9 @@ def make_survey_fn(survey: Survey, cfg: EngineConfig, mesh=None):
             # stats leave the shard_map as [1]-stacks along the mesh axis
             return state, {k: v[None] for k, v in stats.items()}
 
-        sm = shard_map(body, mesh=mesh, in_specs=(mesh_specs(gr, axis),),
-                       out_specs=(P(axis), P(axis)), check_rep=False)
+        sm = jax.shard_map(body, mesh=mesh,
+                           in_specs=(mesh_specs(gr, axis),),
+                           out_specs=(P(axis), P(axis)), check_vma=False)
         state, stats = sm(gr)
         stats = {k: (v[0] if k in _WIRE_STAT_KEYS else v.sum(0))
                  for k, v in stats.items()}

@@ -298,9 +298,8 @@ def plan_shape_signature(cfg: EngineConfig) -> tuple:
             cfg.pull_edge_cap, cfg.n_pull_steps, cfg.pull_row_cap,
             cfg.meta_widths, cfg.transport, cfg.push_caps, cfg.pull_caps,
             cfg.hub_theta, cfg.n_hub_steps, cfg.hub_wedge_cap, cfg.delta,
-            cfg.unroll_steps, cfg.use_pallas, cfg.pull_kernel,
-            cfg.cost_model, cfg.sample_p, cfg.sample_seed,
-            cfg.project_meta, cfg.orient, cfg.shard_axis)
+            cfg.unroll_steps, cfg.use_pallas, cfg.cost_model, cfg.sample_p,
+            cfg.sample_seed, cfg.project_meta, cfg.orient, cfg.shard_axis)
 
 
 # determinism verdicts are pure functions of (survey instance, storage

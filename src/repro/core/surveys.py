@@ -30,6 +30,7 @@ import jax
 import numpy as np
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.core.counting_set import CountingSet
 
 # ---------------------------------------------------------------------------
@@ -548,31 +549,27 @@ class Enumerate(Survey):
     backend-defined, as JAX scatter ties are unordered; ``"pallas"`` is
     the ``kernels/fold_scatter.ring_set`` one-hot kernel, whose wrap
     winner is *deterministic* (highest batch index — the last writer).
-    ``"auto"`` (default) picks Pallas on a real TPU backend and scatter
-    elsewhere, so CPU runs are unchanged. The two backends agree bitwise
-    whenever the buffer does not wrap (every slot has one writer); on
-    wrapped slots only the Pallas winner is reproducible across backends.
+    ``"auto"`` (default) picks the compiled kernel on a TPU backend and
+    scatter elsewhere, so CPU runs are unchanged
+    (:func:`repro.kernels.compiled` is the gate). The two backends agree
+    bitwise whenever the buffer does not wrap (every slot has one
+    writer); on wrapped slots only the Pallas winner is reproducible
+    across backends.
     """
 
     meta_spec = MetaSpec.none()
 
-    def __init__(self, capacity: int, backend: str = "auto",
-                 pallas_interpret: bool | None = None):
+    def __init__(self, capacity: int, backend: str = "auto"):
         if backend not in ("auto", "pallas", "scatter"):
             raise ValueError(f"unknown Enumerate backend {backend!r}")
         self.capacity = capacity
         self.backend = backend
-        self.pallas_interpret = pallas_interpret
 
-    def _use_pallas(self) -> bool:
+    def uses_pallas(self) -> bool:
+        """Whether :meth:`update` runs the ``ring_set`` Pallas kernel."""
         if self.backend == "auto":
-            return jax.default_backend() == "tpu"
+            return kernels.compiled()
         return self.backend == "pallas"
-
-    def _interpret(self) -> bool:
-        if self.pallas_interpret is None:
-            return jax.default_backend() != "tpu"
-        return self.pallas_interpret
 
     def init(self):
         return dict(
@@ -585,7 +582,7 @@ class Enumerate(Survey):
         offs = jnp.cumsum(amt) - amt + state["n"]
         idx = jnp.where(tri.valid, offs % self.capacity, self.capacity)  # OOB drop for invalid
         rows = jnp.stack([tri.p, tri.q, tri.r], -1)
-        if self._use_pallas():
+        if self.uses_pallas():
             from repro.kernels.fold_scatter.ops import ring_set
 
             # carried-table scatter-set with a deterministic wrap winner;
@@ -593,7 +590,7 @@ class Enumerate(Survey):
             # be zeroed (vertex ids are non-negative)
             rows = jnp.where(tri.valid[:, None], rows, 0)
             tris = ring_set(state["tris"], idx, rows, self.capacity,
-                            interpret=self._interpret())
+                            interpret=not kernels.compiled())
         else:
             tris = state["tris"].at[idx].set(rows, mode="drop")
         return dict(tris=tris, n=state["n"] + amt.sum())

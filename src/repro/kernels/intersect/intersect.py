@@ -31,7 +31,7 @@ def _kernel(rd_ref, rh_ref, ri_ref, ln_ref, qd_ref, qh_ref, qi_ref, out_ref,
     qi = qi_ref[...]
 
     lo = jnp.zeros_like(qi)
-    hi = jnp.broadcast_to(ln[:, None], qi.shape)
+    hi = jnp.broadcast_to(ln, qi.shape)
 
     def body(_, carry):
         lo, hi = carry
@@ -55,7 +55,7 @@ def intersect_pallas(row_d, row_h, row_i, ln, qd, qh, qi,
     n_steps = max(1, int(np.ceil(np.log2(max(2, L)))) + 1)
     grid = (B // bb,)
     mat = pl.BlockSpec((bb, L), lambda i: (i, 0))
-    vec = pl.BlockSpec((bb,), lambda i: (i,))
+    vec = pl.BlockSpec((bb, 1), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_kernel, n_steps=n_steps),
         grid=grid,
@@ -63,4 +63,4 @@ def intersect_pallas(row_d, row_h, row_i, ln, qd, qh, qi,
         out_specs=mat,
         out_shape=jax.ShapeDtypeStruct((B, L), jnp.int32),
         interpret=interpret,
-    )(row_d, row_h, row_i, ln, qd, qh, qi)
+    )(row_d, row_h, row_i, ln[:, None], qd, qh, qi)

@@ -21,9 +21,7 @@ Everything served is bitwise-identical to the one-shot ``survey_*`` path
 from repro.serve.coalesce import TenantRequest, coalesce, extract
 from repro.serve.plan_cache import (CacheEntry, PlanCache, entry_nbytes,
                                     load_plan_cache, save_plan_cache)
-from repro.serve.service import (SurveyService,
-                                 enable_persistent_compilation_cache)
+from repro.serve.service import SurveyService
 
 __all__ = ["CacheEntry", "PlanCache", "SurveyService", "TenantRequest",
-           "coalesce", "enable_persistent_compilation_cache", "entry_nbytes",
-           "load_plan_cache", "save_plan_cache"]
+           "coalesce", "entry_nbytes", "load_plan_cache", "save_plan_cache"]

@@ -19,8 +19,9 @@ pipeline across requests and epochs:
 * **restarts** warm-start: :meth:`SurveyService.checkpoint` persists the
   plan cache next to the epoch state (``.plans.npz``) and
   :meth:`SurveyService.restore` preloads it, so the first query after a
-  restart answers from the memoized warm-up state without replanning;
-  pass ``compile_cache_dir=`` to also reuse XLA executables from disk;
+  restart answers from the memoized warm-up state without replanning,
+  and XLA executables come back from JAX's on-disk compilation cache
+  (:func:`repro.utils.enable_compile_cache`);
 * **ingestion** rides :class:`~repro.serve.ingest.IngestPipeline`:
   ``append_edges`` batches become delta epochs on a worker thread
   (sharded with :class:`~repro.core.dodgr.HubTableCache` reuse, resident
@@ -56,30 +57,7 @@ from repro.serve.coalesce import (TenantRequest, coalesce, extract,
 from repro.serve.ingest import IngestPipeline
 from repro.serve.plan_cache import (CacheEntry, PlanCache, entry_nbytes,
                                     load_plan_cache, save_plan_cache)
-
-
-def enable_persistent_compilation_cache(cache_dir) -> bool:
-    """Route XLA compiles through JAX's on-disk compilation cache.
-
-    With this enabled (plus a plan-cache file from
-    :meth:`SurveyService.checkpoint`), a restarted service warm-starts:
-    plans replay from the ``.plans.npz`` and any executable that does get
-    re-traced deserializes from ``cache_dir`` instead of recompiling.
-    Returns False when this jax build has no such config knob."""
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-    except Exception:
-        return False
-    # compile-time/size floors default to skipping small programs; drop
-    # them so the serve-scale traversals always persist (best-effort —
-    # older jax builds lack the knobs)
-    for flag, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(flag, val)
-        except Exception:
-            pass
-    return True
+from repro.utils import enable_compile_cache
 
 
 def _graph_signature(gr) -> tuple:
@@ -145,8 +123,7 @@ class SurveyService:
                  token: str | None = None,
                  epoch: int = 0,
                  cap_policy: str = "bucket",
-                 preload_plans: Sequence[CacheEntry] | None = None,
-                 compile_cache_dir=None):
+                 preload_plans: Sequence[CacheEntry] | None = None):
         if sample_p < 1.0 and resident:
             raise ValueError("resident surveys ride the delta engine, which "
                              "rejects DOULION sampling — serve sampled "
@@ -154,8 +131,7 @@ class SurveyService:
         if cap_policy not in ("exact", "bucket"):
             raise ValueError(f"cap_policy must be 'exact' or 'bucket', "
                              f"got {cap_policy!r}")
-        if compile_cache_dir is not None:
-            enable_persistent_compilation_cache(compile_cache_dir)
+        enable_compile_cache()
         self.S = int(S)
         self.mode = mode
         self.transport = transport

@@ -7,6 +7,9 @@ disabled). The splitmix-style mixer below is the deterministic tie-break
 """
 from __future__ import annotations
 
+import os
+from pathlib import Path
+
 import numpy as np
 import jax.numpy as jnp
 
@@ -20,6 +23,7 @@ __all__ = [
     "pad_axis_to",
     "bucket_cap",
     "bucket_caps",
+    "enable_compile_cache",
 ]
 
 
@@ -140,3 +144,31 @@ def pad_axis_to(x: np.ndarray, axis: int, n: int, fill=0) -> np.ndarray:
     pad = [(0, 0)] * x.ndim
     pad[axis] = (0, n - x.shape[axis])
     return np.pad(x, pad, constant_values=fill)
+
+
+# JAX's persistent compilation cache lives at a fixed path inside the
+# checkout unless JAX_COMPILATION_CACHE_DIR names one: the path is part of
+# every entry's key, so a directory that moved (a temporary name, a pid, a
+# time) would never hit.
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_COMPILE_CACHE = (Path(__file__).resolve().parents[2]
+                          / ".jax_cache")
+
+
+def enable_compile_cache() -> Path:
+    """Turn on JAX's on-disk compilation cache and return its directory.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already uses that
+    directory and nothing is changed; otherwise the cache goes to the
+    fixed ``<checkout>/.jax_cache``. Entry points call this once at start
+    (``chip_smoke.py``, :class:`~repro.serve.SurveyService`), so a
+    restarted process re-reads what an earlier one compiled."""
+    import jax
+
+    env = os.environ.get(COMPILE_CACHE_ENV)
+    if env:
+        return Path(env)
+    if jax.config.jax_compilation_cache_dir != str(CHECKOUT_COMPILE_CACHE):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(CHECKOUT_COMPILE_CACHE))
+    return CHECKOUT_COMPILE_CACHE

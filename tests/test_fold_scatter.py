@@ -169,7 +169,7 @@ def test_counting_set_fused_backend_parity(cap, B, rounds):
     from repro.core.counting_set import CountingSet
 
     rng = np.random.default_rng(cap + B)
-    sets = {b: CountingSet(cap, 2, backend=b, pallas_interpret=True)
+    sets = {b: CountingSet(cap, 2, backend=b)
             for b in ("scatter", "pallas")}
     states = {b: cs.init() for b, cs in sets.items()}
     for r in range(rounds):
@@ -201,7 +201,7 @@ def test_enumerate_fused_backend_parity_no_wrap():
     g = _labeled_graph(64, 400, seed=9)
     out = []
     for backend in ("scatter", "pallas"):
-        sv = Enumerate(4096, backend=backend, pallas_interpret=True)
+        sv = Enumerate(4096, backend=backend)
         cfg, _ = plan_engine(g, 4, sv, mode="pushpull", transport="ragged",
                              push_cap=64, pull_q_cap=4)
         gr, _ = shard_dodgr(g, S=4, hub_theta=cfg.hub_theta, orient="degree")

@@ -19,7 +19,7 @@ import jax
 import numpy as np
 
 from repro.core.dodgr import shard_dodgr
-from repro.core.engine import make_survey_fn
+from repro.core.engine import make_survey_fn, sum_stats
 from repro.core.pushpull import plan_engine
 from repro.core.surveys import DegreeTriples, TriangleCount
 from repro.graphs import generators
@@ -77,6 +77,7 @@ def run(quick=True):
             dt = _timed(fn, gr)
             dt_full = _timed(fn_full, gr)
             merged, st = jax.device_get(fn(gr))
+            st = sum_stats(st)
             merged_full, _ = jax.device_get(fn_full(gr))
             res = survey.finalize(merged)
             res_full = survey.finalize(merged_full)

@@ -19,7 +19,8 @@ import jax
 import numpy as np
 
 from repro.core.dodgr import shard_delta, shard_dodgr
-from repro.core.engine import finalize_epochs, make_survey_fn, survey_delta
+from repro.core.engine import (finalize_epochs, make_survey_fn, sum_stats,
+                               survey_delta)
 from repro.core.pushpull import plan_delta, plan_engine
 from repro.core.surveys import ClosureTime, SurveyBundle, TriangleCount
 from repro.graphs import generators
@@ -92,6 +93,7 @@ def run(quick=True):
         # fold the epoch with the already-compiled fn (what survey_delta
         # would do, minus a redundant re-jit)
         merged, st = jax.device_get(fn(gr_d))
+        st = sum_stats(st)
         state = merged if state is None else survey.merge_epochs(state, merged)
         t_delta_total += t_epoch
         bytes_delta_total += rep_d.pushpull_bytes

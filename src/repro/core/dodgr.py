@@ -24,7 +24,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.graphs.csr import DeltaGraph, HostGraph
-from repro.utils import bucket_cap, ceil_div, splitmix32_np
+from repro.utils import bucket_cap, ceil_div, span, splitmix32_np
 
 PAD_ID = np.int32(2**31 - 1)  # sentinel target id for padded edge slots
 PAD_D = np.int32(2**30)       # sentinel degree (sorts after everything real)
@@ -527,232 +527,242 @@ def shard_dodgr(g: HostGraph, S: int, e_cap: int | None = None,
     keeps the larger shapes (pure padding, still bitwise-identical)
     instead of recompiling for the smaller ones.
     """
-    if cap_policy not in ("exact", "bucket"):
-        raise ValueError(f"cap_policy must be 'exact' or 'bucket', "
-                         f"got {cap_policy!r}")
-    g = sparsify_edges(g, sample_p, sample_seed)
-    sample_p, sample_seed = g.sample_p, g.sample_seed
-    p, q, deg, h = orient_edges(g, orient)
-    d_plus = np.bincount(p, minlength=g.n).astype(np.int64)
+    with span("shard_dodgr"):
+        if cap_policy not in ("exact", "bucket"):
+            raise ValueError(f"cap_policy must be 'exact' or 'bucket', "
+                             f"got {cap_policy!r}")
+        with span("shard_dodgr.orientation"):
+            g = sparsify_edges(g, sample_p, sample_seed)
+            sample_p, sample_seed = g.sample_p, g.sample_seed
+            p, q, deg, h = orient_edges(g, orient)
+            d_plus = np.bincount(p, minlength=g.n).astype(np.int64)
 
-    owner = (p % S).astype(np.int64)
-    local = (p // S).astype(np.int64)
-    n_loc = ceil_div(g.n, S)
+            owner = (p % S).astype(np.int64)
+            local = (p // S).astype(np.int64)
+            n_loc = ceil_div(g.n, S)
 
-    # sort edges by (owner, local row, key(q)) so shard rows are contiguous+sorted
-    order = np.lexsort((q, h[q], deg[q], local, owner))
-    p_s, q_s = p[order], q[order]
-    owner_s, local_s = owner[order], local[order]
+            # sort edges by (owner, local row, key(q)) so shard rows are
+            # contiguous+sorted
+            order = np.lexsort((q, h[q], deg[q], local, owner))
+            p_s, q_s = p[order], q[order]
+            owner_s, local_s = owner[order], local[order]
 
-    counts = np.bincount(owner_s, minlength=S)
-    e_cap_needed = int(counts.max()) if len(counts) else 0
-    if e_cap is None:
-        e_cap = max(8, int(np.ceil(e_cap_needed / 8.0) * 8))
-        if cap_policy == "bucket":
-            e_cap = bucket_cap(e_cap)
-        e_cap = max(e_cap, int(e_cap_floor))
-    if e_cap < e_cap_needed:
-        raise ValueError(f"e_cap {e_cap} < required {e_cap_needed}")
+            counts = np.bincount(owner_s, minlength=S)
+            e_cap_needed = int(counts.max()) if len(counts) else 0
+            if e_cap is None:
+                e_cap = max(8, int(np.ceil(e_cap_needed / 8.0) * 8))
+                if cap_policy == "bucket":
+                    e_cap = bucket_cap(e_cap)
+                e_cap = max(e_cap, int(e_cap_floor))
+            if e_cap < e_cap_needed:
+                raise ValueError(f"e_cap {e_cap} < required {e_cap_needed}")
 
-    start = np.zeros(S + 1, np.int64)
-    start[1:] = np.cumsum(counts)
+            start = np.zeros(S + 1, np.int64)
+            start[1:] = np.cumsum(counts)
 
-    def alloc(shape, dtype, fill=0):
-        a = np.full(shape, fill, dtype)
-        return a
+        with span("shard_dodgr.rows"):
+            def alloc(shape, dtype, fill=0):
+                a = np.full(shape, fill, dtype)
+                return a
 
-    nbr = alloc((S, e_cap), np.int32, PAD_ID)
-    nbr_d = alloc((S, e_cap), np.int32, PAD_D)
-    nbr_h = alloc((S, e_cap), np.uint32)
-    nbr_dp = alloc((S, e_cap), np.int32)
-    edge_src = alloc((S, e_cap), np.int32, PAD_ID)
-    dei, def_, dvi, dvf = (g.spec.dei, g.spec.def_, g.spec.dvi, g.spec.dvf)
-    emeta_i = alloc((S, e_cap, dei), np.int32)
-    emeta_f = alloc((S, e_cap, def_), np.float32)
-    tmeta_i = alloc((S, e_cap, dvi), np.int32)
-    tmeta_f = alloc((S, e_cap, dvf), np.float32)
-    row_ptr = alloc((S, n_loc + 1), np.int32)
-    vmeta_i = alloc((S, n_loc, dvi), np.int32)
-    vmeta_f = alloc((S, n_loc, dvf), np.float32)
-    vdeg = alloc((S, n_loc), np.int32)
-    dplus_arr = alloc((S, n_loc), np.int32)
-    nbr_new = alloc((S, e_cap), bool, False)
-    # all-true for a static snapshot: the engine only consults the mask in
-    # delta mode, where it restricts wedge generation to the three
-    # new-triangle classes
-    delta_gen = alloc((S, e_cap), bool, edge_new is None)
+            nbr = alloc((S, e_cap), np.int32, PAD_ID)
+            nbr_d = alloc((S, e_cap), np.int32, PAD_D)
+            nbr_h = alloc((S, e_cap), np.uint32)
+            nbr_dp = alloc((S, e_cap), np.int32)
+            edge_src = alloc((S, e_cap), np.int32, PAD_ID)
+            dei, def_, dvi, dvf = (g.spec.dei, g.spec.def_, g.spec.dvi, g.spec.dvf)
+            emeta_i = alloc((S, e_cap, dei), np.int32)
+            emeta_f = alloc((S, e_cap, def_), np.float32)
+            tmeta_i = alloc((S, e_cap, dvi), np.int32)
+            tmeta_f = alloc((S, e_cap, dvf), np.float32)
+            row_ptr = alloc((S, n_loc + 1), np.int32)
+            vmeta_i = alloc((S, n_loc, dvi), np.int32)
+            vmeta_f = alloc((S, n_loc, dvf), np.float32)
+            vdeg = alloc((S, n_loc), np.int32)
+            dplus_arr = alloc((S, n_loc), np.int32)
+            nbr_new = alloc((S, e_cap), bool, False)
+            # all-true for a static snapshot: the engine only consults the mask in
+            # delta mode, where it restricts wedge generation to the three
+            # new-triangle classes
+            delta_gen = alloc((S, e_cap), bool, edge_new is None)
 
-    emeta_i_src = g.emeta_i[order]
-    emeta_f_src = g.emeta_f[order]
+            emeta_i_src = g.emeta_i[order]
+            emeta_f_src = g.emeta_f[order]
 
-    # position within row: edges are sorted by (owner, local, key); compute
-    # per-edge suffix length = (row_end - pos - 1)
-    row_key = owner_s * n_loc + local_s
-    _, row_start_idx, row_len = np.unique(row_key, return_index=True, return_counts=True)
-    pos_in_row = np.arange(len(p_s)) - np.repeat(row_start_idx, row_len)
-    suffix = np.repeat(row_len, row_len) - pos_in_row - 1
+            # position within row: edges are sorted by (owner, local, key); compute
+            # per-edge suffix length = (row_end - pos - 1)
+            row_key = owner_s * n_loc + local_s
+            _, row_start_idx, row_len = np.unique(
+                row_key, return_index=True, return_counts=True)
+            pos_in_row = np.arange(len(p_s)) - np.repeat(row_start_idx, row_len)
+            suffix = np.repeat(row_len, row_len) - pos_in_row - 1
 
-    if edge_new is not None:
-        new_s = np.asarray(edge_new, bool)[order]
-        touched = np.zeros(g.n, bool)
-        touched[g.src[edge_new]] = True
-        touched[g.dst[edge_new]] = True
-        gen_s = delta_gen_mask(q_s, row_start_idx, row_len, new_s, touched)
-    else:
-        new_s = gen_s = None
+            if edge_new is not None:
+                new_s = np.asarray(edge_new, bool)[order]
+                touched = np.zeros(g.n, bool)
+                touched[g.src[edge_new]] = True
+                touched[g.dst[edge_new]] = True
+                gen_s = delta_gen_mask(q_s, row_start_idx, row_len, new_s, touched)
+            else:
+                new_s = gen_s = None
 
-    # --- hub table: replicate Adj₊ rows of heavy vertices (deg ≥ θ) ---
-    if hub_theta < 0:
-        raise ValueError(f"hub_theta must be ≥ 0, got {hub_theta}")
-    n_hubs = 0
-    hub_ids = np.zeros(0, np.int64)
-    if hub_theta >= 1:
-        tdeg = deg if orient == "degree" else g.degrees()
-        hub_ids = np.nonzero(tdeg >= hub_theta)[0]
-        n_hubs = len(hub_ids)
-    hc = max(1, n_hubs)
-    hub_rows = "frontier"
-    hub_len = 1
-    hub_of_q = None
-    if n_hubs:
-        hub_id_of = np.full(g.n, -1, np.int32)
-        hub_id_of[hub_ids] = np.arange(n_hubs, dtype=np.int32)
-        hub_of_q = hub_id_of[q_s]
-    if hub_tables is not None and hub_theta >= 1:
-        # cache-served union rows (HubTableCache.build): the hub SET must
-        # still be this view's — the planner removed exactly these wedges
-        # from the wire lanes, and nbr_hub below marks exactly these edges
-        if not np.array_equal(np.asarray(hub_tables["hub_ids"], np.int64),
-                              hub_ids.astype(np.int64)):
-            raise ValueError(
-                "hub_tables was built for a different hub set than "
-                f"deg ≥ {hub_theta} selects in this view; build it from "
-                "this epoch's frontier degrees")
-        hub_rows = str(hub_tables["hub_rows"])
-        hub_len = int(hub_tables["hub_len"])
-        hub_row_len = np.asarray(hub_tables["hub_row_len"], np.int32)
-        hub_nbr = np.asarray(hub_tables["hub_nbr"], np.int32)
-        hub_nbr_d = np.asarray(hub_tables["hub_nbr_d"], np.int32)
-        hub_nbr_h = np.asarray(hub_tables["hub_nbr_h"], np.uint32)
-        hub_nbr_new = np.asarray(hub_tables["hub_nbr_new"], bool)
-        hub_eqr_i = np.asarray(hub_tables["hub_eqr_i"], np.int32)
-        hub_eqr_f = np.asarray(hub_tables["hub_eqr_f"], np.float32)
-        hub_tmeta_i = np.asarray(hub_tables["hub_tmeta_i"], np.int32)
-        hub_tmeta_f = np.asarray(hub_tables["hub_tmeta_f"], np.float32)
-        hub_vmeta_i = np.asarray(hub_tables["hub_vmeta_i"], np.int32)
-        hub_vmeta_f = np.asarray(hub_tables["hub_vmeta_f"], np.float32)
-    else:
-        hub_row_len = np.zeros(hc, np.int32)
-        if n_hubs:
-            hub_row_len[:n_hubs] = d_plus[hub_ids]
-            hub_len = max(1, int(d_plus[hub_ids].max()))
+        with span("shard_dodgr.hub_table"):
+            # --- hub table: replicate Adj₊ rows of heavy vertices (deg ≥ θ) ---
+            if hub_theta < 0:
+                raise ValueError(f"hub_theta must be ≥ 0, got {hub_theta}")
+            n_hubs = 0
+            hub_ids = np.zeros(0, np.int64)
+            if hub_theta >= 1:
+                tdeg = deg if orient == "degree" else g.degrees()
+                hub_ids = np.nonzero(tdeg >= hub_theta)[0]
+                n_hubs = len(hub_ids)
+            hc = max(1, n_hubs)
+            hub_rows = "frontier"
+            hub_len = 1
+            hub_of_q = None
+            if n_hubs:
+                hub_id_of = np.full(g.n, -1, np.int32)
+                hub_id_of[hub_ids] = np.arange(n_hubs, dtype=np.int32)
+                hub_of_q = hub_id_of[q_s]
+            if hub_tables is not None and hub_theta >= 1:
+                # cache-served union rows (HubTableCache.build): the hub SET must
+                # still be this view's — the planner removed exactly these wedges
+                # from the wire lanes, and nbr_hub below marks exactly these edges
+                if not np.array_equal(np.asarray(hub_tables["hub_ids"], np.int64),
+                                      hub_ids.astype(np.int64)):
+                    raise ValueError(
+                        "hub_tables was built for a different hub set than "
+                        f"deg ≥ {hub_theta} selects in this view; build it from "
+                        "this epoch's frontier degrees")
+                hub_rows = str(hub_tables["hub_rows"])
+                hub_len = int(hub_tables["hub_len"])
+                hub_row_len = np.asarray(hub_tables["hub_row_len"], np.int32)
+                hub_nbr = np.asarray(hub_tables["hub_nbr"], np.int32)
+                hub_nbr_d = np.asarray(hub_tables["hub_nbr_d"], np.int32)
+                hub_nbr_h = np.asarray(hub_tables["hub_nbr_h"], np.uint32)
+                hub_nbr_new = np.asarray(hub_tables["hub_nbr_new"], bool)
+                hub_eqr_i = np.asarray(hub_tables["hub_eqr_i"], np.int32)
+                hub_eqr_f = np.asarray(hub_tables["hub_eqr_f"], np.float32)
+                hub_tmeta_i = np.asarray(hub_tables["hub_tmeta_i"], np.int32)
+                hub_tmeta_f = np.asarray(hub_tables["hub_tmeta_f"], np.float32)
+                hub_vmeta_i = np.asarray(hub_tables["hub_vmeta_i"], np.int32)
+                hub_vmeta_f = np.asarray(hub_tables["hub_vmeta_f"], np.float32)
+            else:
+                hub_row_len = np.zeros(hc, np.int32)
+                if n_hubs:
+                    hub_row_len[:n_hubs] = d_plus[hub_ids]
+                    hub_len = max(1, int(d_plus[hub_ids].max()))
+                    if cap_policy == "bucket":
+                        hub_len = bucket_cap(hub_len)
+                hub_nbr = alloc((hc, hub_len), np.int32, PAD_ID)
+                hub_nbr_d = alloc((hc, hub_len), np.int32, PAD_D)
+                hub_nbr_h = alloc((hc, hub_len), np.uint32)
+                hub_nbr_new = alloc((hc, hub_len), bool, False)
+                hub_eqr_i = alloc((hc, hub_len, dei), np.int32)
+                hub_eqr_f = alloc((hc, hub_len, def_), np.float32)
+                hub_tmeta_i = alloc((hc, hub_len, dvi), np.int32)
+                hub_tmeta_f = alloc((hc, hub_len, dvf), np.float32)
+                hub_vmeta_i = alloc((hc, dvi), np.int32)
+                hub_vmeta_f = alloc((hc, dvf), np.float32)
+                if n_hubs:
+                    # rows of hub pivots are contiguous runs of the sorted edge
+                    # list, so the replicated table is a verbatim copy of the owner
+                    # shards' rows
+                    he = np.nonzero(hub_id_of[p_s] >= 0)[0]
+                    hid = hub_id_of[p_s[he]]
+                    hpos = pos_in_row[he]
+                    hub_nbr[hid, hpos] = q_s[he]
+                    hub_nbr_d[hid, hpos] = deg[q_s[he]]
+                    hub_nbr_h[hid, hpos] = h[q_s[he]].astype(np.uint32)
+                    hub_eqr_i[hid, hpos] = emeta_i_src[he]
+                    hub_eqr_f[hid, hpos] = emeta_f_src[he]
+                    hub_tmeta_i[hid, hpos] = g.vmeta_i[q_s[he]]
+                    hub_tmeta_f[hid, hpos] = g.vmeta_f[q_s[he]]
+                    hub_vmeta_i[:n_hubs] = g.vmeta_i[hub_ids]
+                    hub_vmeta_f[:n_hubs] = g.vmeta_f[hub_ids]
+                    if new_s is not None:
+                        hub_nbr_new[hid, hpos] = new_s[he]
+            nbr_hub = alloc((S, e_cap), np.int32, -1)
+
+        with span("shard_dodgr.shards"):
+            for s in range(S):
+                lo, hi = start[s], start[s + 1]
+                k = hi - lo
+                nbr[s, :k] = q_s[lo:hi]
+                nbr_d[s, :k] = deg[q_s[lo:hi]]
+                nbr_h[s, :k] = h[q_s[lo:hi]].astype(np.uint32)
+                nbr_dp[s, :k] = d_plus[q_s[lo:hi]]
+                edge_src[s, :k] = p_s[lo:hi]
+                emeta_i[s, :k] = emeta_i_src[lo:hi]
+                emeta_f[s, :k] = emeta_f_src[lo:hi]
+                tmeta_i[s, :k] = g.vmeta_i[q_s[lo:hi]]
+                tmeta_f[s, :k] = g.vmeta_f[q_s[lo:hi]]
+                if new_s is not None:
+                    nbr_new[s, :k] = new_s[lo:hi]
+                    delta_gen[s, :k] = gen_s[lo:hi]
+                    delta_gen[s, k:] = False
+                if hub_of_q is not None:
+                    nbr_hub[s, :k] = hub_of_q[lo:hi]
+                rows = np.bincount(local_s[lo:hi], minlength=n_loc)
+                row_ptr[s, 1:] = np.cumsum(rows)
+                ids = np.arange(s, g.n, S, dtype=np.int64)
+                nv = len(ids)
+                vmeta_i[s, :nv] = g.vmeta_i[ids]
+                vmeta_f[s, :nv] = g.vmeta_f[ids]
+                vdeg[s, :nv] = deg[ids]
+                dplus_arr[s, :nv] = d_plus[ids]
+
+        with span("shard_dodgr.routing_stats"):
+            # --- routing stats for static superstep planning ---
+            dest = (q_s % S).astype(np.int64)
+            sd = owner_s * S + dest
+            stream = np.bincount(sd, weights=suffix, minlength=S * S).astype(np.int64)
+            pairs = np.bincount(sd, minlength=S * S)
+            stats = RoutingStats(
+                wedges_total=int(suffix.sum()),
+                max_stream=int(stream.max()) if len(stream) else 0,
+                max_pairs=int(pairs.max()) if len(pairs) else 0,
+                edges_per_shard=counts,
+                wedge_per_shard=np.bincount(
+                    owner_s, weights=suffix, minlength=S).astype(np.int64),
+            )
+
+        with span("shard_dodgr.device_arrays"):
+            d_plus_max = max(1, int(d_plus.max()) if g.n else 0)
             if cap_policy == "bucket":
-                hub_len = bucket_cap(hub_len)
-        hub_nbr = alloc((hc, hub_len), np.int32, PAD_ID)
-        hub_nbr_d = alloc((hc, hub_len), np.int32, PAD_D)
-        hub_nbr_h = alloc((hc, hub_len), np.uint32)
-        hub_nbr_new = alloc((hc, hub_len), bool, False)
-        hub_eqr_i = alloc((hc, hub_len, dei), np.int32)
-        hub_eqr_f = alloc((hc, hub_len, def_), np.float32)
-        hub_tmeta_i = alloc((hc, hub_len, dvi), np.int32)
-        hub_tmeta_f = alloc((hc, hub_len, dvf), np.float32)
-        hub_vmeta_i = alloc((hc, dvi), np.int32)
-        hub_vmeta_f = alloc((hc, dvf), np.float32)
-        if n_hubs:
-            # rows of hub pivots are contiguous runs of the sorted edge
-            # list, so the replicated table is a verbatim copy of the owner
-            # shards' rows
-            he = np.nonzero(hub_id_of[p_s] >= 0)[0]
-            hid = hub_id_of[p_s[he]]
-            hpos = pos_in_row[he]
-            hub_nbr[hid, hpos] = q_s[he]
-            hub_nbr_d[hid, hpos] = deg[q_s[he]]
-            hub_nbr_h[hid, hpos] = h[q_s[he]].astype(np.uint32)
-            hub_eqr_i[hid, hpos] = emeta_i_src[he]
-            hub_eqr_f[hid, hpos] = emeta_f_src[he]
-            hub_tmeta_i[hid, hpos] = g.vmeta_i[q_s[he]]
-            hub_tmeta_f[hid, hpos] = g.vmeta_f[q_s[he]]
-            hub_vmeta_i[:n_hubs] = g.vmeta_i[hub_ids]
-            hub_vmeta_f[:n_hubs] = g.vmeta_f[hub_ids]
-            if new_s is not None:
-                hub_nbr_new[hid, hpos] = new_s[he]
-    nbr_hub = alloc((S, e_cap), np.int32, -1)
-
-    for s in range(S):
-        lo, hi = start[s], start[s + 1]
-        k = hi - lo
-        nbr[s, :k] = q_s[lo:hi]
-        nbr_d[s, :k] = deg[q_s[lo:hi]]
-        nbr_h[s, :k] = h[q_s[lo:hi]].astype(np.uint32)
-        nbr_dp[s, :k] = d_plus[q_s[lo:hi]]
-        edge_src[s, :k] = p_s[lo:hi]
-        emeta_i[s, :k] = emeta_i_src[lo:hi]
-        emeta_f[s, :k] = emeta_f_src[lo:hi]
-        tmeta_i[s, :k] = g.vmeta_i[q_s[lo:hi]]
-        tmeta_f[s, :k] = g.vmeta_f[q_s[lo:hi]]
-        if new_s is not None:
-            nbr_new[s, :k] = new_s[lo:hi]
-            delta_gen[s, :k] = gen_s[lo:hi]
-            delta_gen[s, k:] = False
-        if hub_of_q is not None:
-            nbr_hub[s, :k] = hub_of_q[lo:hi]
-        rows = np.bincount(local_s[lo:hi], minlength=n_loc)
-        row_ptr[s, 1:] = np.cumsum(rows)
-        ids = np.arange(s, g.n, S, dtype=np.int64)
-        nv = len(ids)
-        vmeta_i[s, :nv] = g.vmeta_i[ids]
-        vmeta_f[s, :nv] = g.vmeta_f[ids]
-        vdeg[s, :nv] = deg[ids]
-        dplus_arr[s, :nv] = d_plus[ids]
-
-    # --- routing stats for static superstep planning ---
-    dest = (q_s % S).astype(np.int64)
-    sd = owner_s * S + dest
-    stream = np.bincount(sd, weights=suffix, minlength=S * S).astype(np.int64)
-    pairs = np.bincount(sd, minlength=S * S)
-    stats = RoutingStats(
-        wedges_total=int(suffix.sum()),
-        max_stream=int(stream.max()) if len(stream) else 0,
-        max_pairs=int(pairs.max()) if len(pairs) else 0,
-        edges_per_shard=counts,
-        wedge_per_shard=np.bincount(owner_s, weights=suffix, minlength=S).astype(np.int64),
-    )
-
-    d_plus_max = max(1, int(d_plus.max()) if g.n else 0)
-    if cap_policy == "bucket":
-        # d_plus_max is a static meta field (part of every jit signature)
-        # AND the fallback reply-row window when a plan leaves
-        # pull_row_cap=0 — every consumer masks by the true row length,
-        # so rounding it up is pure padding
-        d_plus_max = bucket_cap(d_plus_max)
-    d_plus_max = max(d_plus_max, int(d_plus_max_floor))
-    gr = ShardedDODGr(
-        S=S, n_global=g.n, n_loc=n_loc, e_cap=e_cap,
-        d_plus_max=d_plus_max,
-        sample_p=sample_p, sample_seed=sample_seed,
-        orient=orient, epoch=epoch, is_delta=edge_new is not None,
-        hub_theta=hub_theta, n_hubs=n_hubs, hub_len=hub_len,
-        hub_rows=hub_rows,
-        row_ptr=jnp.asarray(row_ptr), edge_src=jnp.asarray(edge_src),
-        nbr=jnp.asarray(nbr), nbr_d=jnp.asarray(nbr_d),
-        nbr_h=jnp.asarray(nbr_h), nbr_dplus=jnp.asarray(nbr_dp),
-        emeta_i=jnp.asarray(emeta_i), emeta_f=jnp.asarray(emeta_f),
-        tmeta_i=jnp.asarray(tmeta_i), tmeta_f=jnp.asarray(tmeta_f),
-        vmeta_i=jnp.asarray(vmeta_i), vmeta_f=jnp.asarray(vmeta_f),
-        vdeg=jnp.asarray(vdeg), dplus=jnp.asarray(dplus_arr),
-        nbr_new=jnp.asarray(nbr_new), delta_gen=jnp.asarray(delta_gen),
-        nbr_hub=jnp.asarray(nbr_hub),
-        hub_row_len=jnp.asarray(hub_row_len),
-        hub_nbr=jnp.asarray(hub_nbr), hub_nbr_d=jnp.asarray(hub_nbr_d),
-        hub_nbr_h=jnp.asarray(hub_nbr_h),
-        hub_nbr_new=jnp.asarray(hub_nbr_new),
-        hub_eqr_i=jnp.asarray(hub_eqr_i), hub_eqr_f=jnp.asarray(hub_eqr_f),
-        hub_tmeta_i=jnp.asarray(hub_tmeta_i),
-        hub_tmeta_f=jnp.asarray(hub_tmeta_f),
-        hub_vmeta_i=jnp.asarray(hub_vmeta_i),
-        hub_vmeta_f=jnp.asarray(hub_vmeta_f),
-    )
-    return gr, stats
+                # d_plus_max is a static meta field (part of every jit signature)
+                # AND the fallback reply-row window when a plan leaves
+                # pull_row_cap=0 — every consumer masks by the true row length,
+                # so rounding it up is pure padding
+                d_plus_max = bucket_cap(d_plus_max)
+            d_plus_max = max(d_plus_max, int(d_plus_max_floor))
+            gr = ShardedDODGr(
+                S=S, n_global=g.n, n_loc=n_loc, e_cap=e_cap,
+                d_plus_max=d_plus_max,
+                sample_p=sample_p, sample_seed=sample_seed,
+                orient=orient, epoch=epoch, is_delta=edge_new is not None,
+                hub_theta=hub_theta, n_hubs=n_hubs, hub_len=hub_len,
+                hub_rows=hub_rows,
+                row_ptr=jnp.asarray(row_ptr), edge_src=jnp.asarray(edge_src),
+                nbr=jnp.asarray(nbr), nbr_d=jnp.asarray(nbr_d),
+                nbr_h=jnp.asarray(nbr_h), nbr_dplus=jnp.asarray(nbr_dp),
+                emeta_i=jnp.asarray(emeta_i), emeta_f=jnp.asarray(emeta_f),
+                tmeta_i=jnp.asarray(tmeta_i), tmeta_f=jnp.asarray(tmeta_f),
+                vmeta_i=jnp.asarray(vmeta_i), vmeta_f=jnp.asarray(vmeta_f),
+                vdeg=jnp.asarray(vdeg), dplus=jnp.asarray(dplus_arr),
+                nbr_new=jnp.asarray(nbr_new), delta_gen=jnp.asarray(delta_gen),
+                nbr_hub=jnp.asarray(nbr_hub),
+                hub_row_len=jnp.asarray(hub_row_len),
+                hub_nbr=jnp.asarray(hub_nbr), hub_nbr_d=jnp.asarray(hub_nbr_d),
+                hub_nbr_h=jnp.asarray(hub_nbr_h),
+                hub_nbr_new=jnp.asarray(hub_nbr_new),
+                hub_eqr_i=jnp.asarray(hub_eqr_i), hub_eqr_f=jnp.asarray(hub_eqr_f),
+                hub_tmeta_i=jnp.asarray(hub_tmeta_i),
+                hub_tmeta_f=jnp.asarray(hub_tmeta_f),
+                hub_vmeta_i=jnp.asarray(hub_vmeta_i),
+                hub_vmeta_f=jnp.asarray(hub_vmeta_f),
+            )
+            return gr, stats
 
 
 def shard_delta(dg: DeltaGraph, S: int, e_cap: int | None = None,

@@ -81,7 +81,7 @@ from repro.comm.exchange import Exchange, make_exchange
 from repro.core.dodgr import ShardedDODGr, meta_widths
 from repro.core.surveys import (MetaSpec, Survey, TriangleBatch, expand_lanes,
                                 narrow_lanes, project_lanes)
-from repro.utils import ceil_div
+from repro.utils import ceil_div, span
 
 BIG_I32 = jnp.int32(2**30)
 
@@ -446,7 +446,7 @@ def _hub_superstep(gr: ShardedDODGr, hst, t, cfg: EngineConfig,
             e_pq_f=epq_f[e], e_pr_f=epr_f[r_pos], e_qr_f=h_eqr_f[pos_c],
             valid=found,
         )
-        return tri, ok.sum(dtype=jnp.float32)
+        return tri, ok.sum(dtype=jnp.int32)
 
     return jax.vmap(per_shard)(
         hst["cum"], hst["total"], gr.edge_src, gr.nbr, gr.nbr_d, gr.nbr_h,
@@ -626,7 +626,7 @@ def _pull_wire(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
         vq_i=expand_lanes(rep["vq_i"], spec.vq_i),
         vq_f=expand_lanes(rep["vq_f"], spec.vq_f),
     )
-    return rep, req["ok"].sum(dtype=jnp.float32)
+    return rep, req["ok"].sum(dtype=jnp.int32)
 
 
 # Requester-side staging budget of one pull superstep, per device. The
@@ -670,8 +670,9 @@ def _pull_compute(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
     needs — so the staged batch stays within :data:`PULL_STAGE_BYTES`
     and no slot is spent on an empty suffix position. Ranks follow the
     (dest, edge, suffix) order, so every survey sees its triangles in one
-    fixed sequence. Returns ``(state, tris, checked, overflow)``, the
-    counts per shard."""
+    fixed sequence. Returns ``(state, tris, checked, overflow, slots)``:
+    the counts per shard, and the wedge slots the tiles staged
+    (``n_tiles × T × S_ax``, filled or not)."""
     S, e_cap, n_loc = gr.S, gr.e_cap, gr.n_loc
     S_ax = gr.row_ptr.shape[0]
     ecap = cfg.pull_edge_cap
@@ -736,53 +737,63 @@ def _pull_compute(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
         ridx = jnp.clip(boff[d] + slot, 0, out_cap - 1)   # flat reply row
         return dict(e=e, ridx=ridx, cum=jnp.cumsum(w)), overflow
 
-    win, overflow = jax.vmap(window)(
-        ps["qrank2"], ps["qbase"], ps["qcount"], ps["pulled_end"],
-        ps["dest_start2"], ps["ord2"], ps["pull"], gr.delta_gen, gr.row_ptr,
-        gr.edge_src, pcap_d, boff)
-    total = win["cum"][:, -1]                              # wedges per shard
+    with jax.named_scope("engine/pull/rank"):
+        win, overflow = jax.vmap(window)(
+            ps["qrank2"], ps["qbase"], ps["qcount"], ps["pulled_end"],
+            ps["dest_start2"], ps["ord2"], ps["pull"], gr.delta_gen,
+            gr.row_ptr, gr.edge_src, pcap_d, boff)
+        total = win["cum"][:, -1]                          # wedges per shard
 
     def wedges(rank0, win, row_ptr, edge_src, nbr, nbr_d, nbr_h, nbr_new,
                epq_i, epq_f, epr_i, epr_f, vp_i, vp_f, rp, rows):
         """Tile of wedge ranks [rank0, rank0 + T): locate each wedge's
         edge and suffix position, search its key in the pulled row."""
-        cum = win["cum"]
-        rank = rank0 + jnp.arange(T, dtype=jnp.int32)
-        ok = rank < cum[-1]
-        r = jnp.clip(jnp.searchsorted(cum, rank, side="right"),
-                     0, S * ecap - 1).astype(jnp.int32)
-        prev = jnp.where(r > 0, cum[jnp.maximum(r - 1, 0)], 0)
-        e = win["e"][r]
-        ridx = win["ridx"][r]
-        r_pos = jnp.clip(e + 1 + rank - prev, 0, e_cap - 1)
-        ci = nbr[r_pos]
-        lo = ridx * Lr
-        hi = lo + rp["ln"][ridx]
-        if cfg.use_pallas:
-            pos = wc_ops.wedge_check(rows["r_d"], rows["r_h"], rows["r_nbr"],
-                                     lo, hi, nbr_d[r_pos], nbr_h[r_pos], ci,
-                                     interpret=not kernels.compiled())
-        else:
-            pos = _lower_bound(rows["r_d"], rows["r_h"], rows["r_nbr"], lo,
-                               hi, nbr_d[r_pos], nbr_h[r_pos], ci, n_steps)
-        pos_c = jnp.clip(pos, 0, out_cap * Lr - 1)
-        # the reply header's ok word (the owner's view of request validity)
-        # rides back with the rows; AND-ing it in is a no-op on every slot
-        # the requester's own maps admit, and keeps the planned header word
-        # live on the wire
-        hit = (ok & rp["ok"][ridx] & (pos < hi)
-               & (rows["r_nbr"][pos_c] == ci))
-        if cfg.delta:
-            hit &= nbr_new[e] | nbr_new[r_pos] | rows["r_new"][pos_c]
-        lp = jnp.clip(edge_src[e] // S, 0, n_loc - 1)
-        return TriangleBatch(
-            p=edge_src[e], q=nbr[e], r=ci,
-            vp_i=vp_i[lp], vq_i=rp["vq_i"][ridx], vr_i=rows["r_ti"][pos_c],
-            vp_f=vp_f[lp], vq_f=rp["vq_f"][ridx], vr_f=rows["r_tf"][pos_c],
-            e_pq_i=epq_i[e], e_pr_i=epr_i[r_pos], e_qr_i=rows["r_ei"][pos_c],
-            e_pq_f=epq_f[e], e_pr_f=epr_f[r_pos], e_qr_f=rows["r_ef"][pos_c],
-            valid=hit,
-        )
+        with jax.named_scope("engine/pull/rank"):
+            cum = win["cum"]
+            rank = rank0 + jnp.arange(T, dtype=jnp.int32)
+            ok = rank < cum[-1]
+            r = jnp.clip(jnp.searchsorted(cum, rank, side="right"),
+                         0, S * ecap - 1).astype(jnp.int32)
+            prev = jnp.where(r > 0, cum[jnp.maximum(r - 1, 0)], 0)
+            e = win["e"][r]
+            ridx = win["ridx"][r]
+            r_pos = jnp.clip(e + 1 + rank - prev, 0, e_cap - 1)
+        # the key search, the hit test and the gathers at the found slot
+        with jax.named_scope("engine/pull/search"):
+            ci = nbr[r_pos]
+            lo = ridx * Lr
+            hi = lo + rp["ln"][ridx]
+            if cfg.use_pallas:
+                pos = wc_ops.wedge_check(
+                    rows["r_d"], rows["r_h"], rows["r_nbr"], lo, hi,
+                    nbr_d[r_pos], nbr_h[r_pos], ci,
+                    interpret=not kernels.compiled())
+            else:
+                pos = _lower_bound(rows["r_d"], rows["r_h"], rows["r_nbr"],
+                                   lo, hi, nbr_d[r_pos], nbr_h[r_pos], ci,
+                                   n_steps)
+            pos_c = jnp.clip(pos, 0, out_cap * Lr - 1)
+            # the reply header's ok word (the owner's view of request
+            # validity) rides back with the rows; AND-ing it in is a no-op
+            # on every slot the requester's own maps admit, and keeps the
+            # planned header word live on the wire
+            hit = (ok & rp["ok"][ridx] & (pos < hi)
+                   & (rows["r_nbr"][pos_c] == ci))
+            if cfg.delta:
+                hit &= nbr_new[e] | nbr_new[r_pos] | rows["r_new"][pos_c]
+            lp = jnp.clip(edge_src[e] // S, 0, n_loc - 1)
+            return TriangleBatch(
+                p=edge_src[e], q=nbr[e], r=ci,
+                vp_i=vp_i[lp], vq_i=rp["vq_i"][ridx],
+                vr_i=rows["r_ti"][pos_c],
+                vp_f=vp_f[lp], vq_f=rp["vq_f"][ridx],
+                vr_f=rows["r_tf"][pos_c],
+                e_pq_i=epq_i[e], e_pr_i=epr_i[r_pos],
+                e_qr_i=rows["r_ei"][pos_c],
+                e_pq_f=epq_f[e], e_pr_f=epr_f[r_pos],
+                e_qr_f=rows["r_ef"][pos_c],
+                valid=hit,
+            )
 
     def tile(i, carry):
         state, tris = carry
@@ -790,13 +801,16 @@ def _pull_compute(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
             win, gr.row_ptr, gr.edge_src, gr.nbr, gr.nbr_d, gr.nbr_h,
             gr.nbr_new, epq_i_l, epq_f_l, epr_i_l, epr_f_l, vp_i_l, vp_f_l,
             rep, rows)
-        state = jax.vmap(survey.update)(state, tri)
-        return state, tris + tri.valid.sum(axis=1, dtype=jnp.int32)
+        with jax.named_scope("engine/fold"):
+            state = jax.vmap(survey.update)(state, tri)
+        with jax.named_scope("engine/pull/search"):
+            tris = tris + tri.valid.sum(axis=1, dtype=jnp.int32)
+        return state, tris
 
     n_tiles = (total.max() + (T - 1)) // T
     state, tris = jax.lax.fori_loop(
         0, n_tiles, tile, (state, jnp.zeros((S_ax,), jnp.int32)))
-    return state, tris, total, overflow
+    return state, tris, total, overflow, n_tiles * (T * S_ax)
 
 
 # ---------------------------------------------------------------------------
@@ -807,6 +821,21 @@ def _pull_compute(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
 # value, so the mesh path keeps one copy instead of summing over devices
 _WIRE_STAT_KEYS = ("wire_push_words", "wire_req_words", "wire_reply_words")
 
+# the counted stats. On the device each is an int32 vector of
+# per-superstep counts (a lane's scan output, or one setup-time entry); the
+# host adds them up as Python ints (:func:`sum_stats`), so a survey's
+# counts stay exact however many supersteps it runs
+STAT_KEYS = ("wedges_pushed", "tris_push", "wedges_pulled", "tris_pull",
+             "wedges_hub", "tris_hub", "pull_requests", "pull_overflow",
+             "stream_dropped") + _WIRE_STAT_KEYS + ("pull_tile_slots",)
+
+
+def sum_stats(stats) -> dict:
+    """Host-side totals of a survey program's per-superstep stats: each
+    key's int32 parts summed as Python ints (exact at any size)."""
+    return {k: int(np.asarray(v, np.int64).sum())
+            for k, v in jax.device_get(stats).items()}
+
 
 def _survey_body(gr: ShardedDODGr, survey: Survey, cfg: EngineConfig,
                  spec: MetaSpec, push_exch: Exchange,
@@ -815,10 +844,9 @@ def _survey_body(gr: ShardedDODGr, survey: Survey, cfg: EngineConfig,
     stacked path ``gr`` carries all ``S`` shards ([S, ...] leaves, host
     transports); under ``shard_map`` it is one device's shard ([1, ...]
     leaves, a :class:`~repro.comm.mesh_exchange.LocalMeshView` per lane).
-    Returns the *unmerged* per-shard state stack and per-call stats."""
+    Returns the *unmerged* per-shard state stack and the per-superstep
+    stats (``STAT_KEYS``, int32 vectors)."""
     S_ax = gr.row_ptr.shape[0]    # leading shard axis: S stacked, 1 on mesh
-    state = jax.tree.map(lambda x: jnp.repeat(x[None], S_ax, 0),
-                         survey.init())
 
     # routing tables live across every superstep: pin them to the shard
     # axis or the partitioner replicates the [S, e_cap] masks per device
@@ -838,184 +866,190 @@ def _survey_body(gr: ShardedDODGr, survey: Survey, cfg: EngineConfig,
     is_hub = (gr.nbr_hub >= 0) if hub_on else None
     gen = gr.delta_gen if cfg.delta else None
 
-    dropped = jnp.zeros((), jnp.float32)
-    push_caps_j = jnp.asarray(push_exch.caps, jnp.int32)
-    if cfg.mode == "pushpull":
-        st0 = pin(_stream_setup(gr))
-        sfx = st0["suffix"]
-        if cfg.delta:
-            # pull decisions weigh only wedges the delta mask generates,
-            # mirroring the planner's masked vol(s, q)
-            sfx = sfx * gen
-        if hub_on:
-            # hub-centered groups carry zero pullable volume
-            sfx = sfx * (~is_hub)
-        st0 = dict(st0, suffix=sfx)
-        ps = pin(_pull_setup(gr, st0, cfg, mw, hub_mask=is_hub))
-        push_mask = ~ps["pull"]
-        if cfg.delta:
-            push_mask = push_mask & gen
-        if hub_on:
-            push_mask = push_mask & ~is_hub
-        st = pin(_stream_setup(gr, weight_mask=push_mask))
-        pull_caps_j = jnp.asarray(pull_exch.caps, jnp.int32)
+    with jax.named_scope("engine/setup"):
+        state = jax.tree.map(lambda x: jnp.repeat(x[None], S_ax, 0),
+                             survey.init())
+        dropped = jnp.zeros((), jnp.int32)
+        push_caps_j = jnp.asarray(push_exch.caps, jnp.int32)
+        if cfg.mode == "pushpull":
+            st0 = pin(_stream_setup(gr))
+            sfx = st0["suffix"]
+            if cfg.delta:
+                # pull decisions weigh only wedges the delta mask
+                # generates, mirroring the planner's masked vol(s, q)
+                sfx = sfx * gen
+            if hub_on:
+                # hub-centered groups carry zero pullable volume
+                sfx = sfx * (~is_hub)
+            st0 = dict(st0, suffix=sfx)
+            ps = pin(_pull_setup(gr, st0, cfg, mw, hub_mask=is_hub))
+            push_mask = ~ps["pull"]
+            if cfg.delta:
+                push_mask = push_mask & gen
+            if hub_on:
+                push_mask = push_mask & ~is_hub
+            st = pin(_stream_setup(gr, weight_mask=push_mask))
+            pull_caps_j = jnp.asarray(pull_exch.caps, jnp.int32)
+            dropped += jnp.maximum(
+                ps["qcount"] - cfg.n_pull_steps * pull_caps_j, 0
+            ).sum(dtype=jnp.int32)
+        else:
+            ps = None
+            wm = None
+            if cfg.delta and hub_on:
+                wm = gen & ~is_hub
+            elif cfg.delta:
+                wm = gen
+            elif hub_on:
+                wm = ~is_hub
+            st = pin(_stream_setup(gr, weight_mask=wm))
         dropped += jnp.maximum(
-            ps["qcount"] - cfg.n_pull_steps * pull_caps_j, 0
-        ).sum(dtype=jnp.float32)
-    else:
-        ps = None
-        wm = None
-        if cfg.delta and hub_on:
-            wm = gen & ~is_hub
-        elif cfg.delta:
-            wm = gen
-        elif hub_on:
-            wm = ~is_hub
-        st = pin(_stream_setup(gr, weight_mask=wm))
-    dropped += jnp.maximum(
-        st["stream_len"] - cfg.n_push_steps * push_caps_j, 0
-    ).sum(dtype=jnp.float32)
+            st["stream_len"] - cfg.n_push_steps * push_caps_j, 0
+        ).sum(dtype=jnp.int32)
 
-    if hub_on:
-        hmask = is_hub if gen is None else (is_hub & gen)
-        hst = pin(_hub_setup(gr, st, hmask))
-        dropped += jnp.maximum(
-            hst["total"] - cfg.n_hub_steps * cfg.hub_wedge_cap, 0
-        ).sum(dtype=jnp.float32)
+        if hub_on:
+            hmask = is_hub if gen is None else (is_hub & gen)
+            hst = pin(_hub_setup(gr, st, hmask))
+            dropped += jnp.maximum(
+                hst["total"] - cfg.n_hub_steps * cfg.hub_wedge_cap, 0
+            ).sum(dtype=jnp.int32)
 
-    stats = dict(
-        wedges_pushed=jnp.zeros((), jnp.float32),
-        tris_push=jnp.zeros((), jnp.float32),
-        wedges_pulled=jnp.zeros((), jnp.float32),
-        tris_pull=jnp.zeros((), jnp.float32),
-        wedges_hub=jnp.zeros((), jnp.float32),
-        tris_hub=jnp.zeros((), jnp.float32),
-        pull_requests=jnp.zeros((), jnp.float32),
-        pull_overflow=jnp.zeros((), jnp.float32),
-        stream_dropped=dropped,
-        wire_push_words=jnp.zeros((), jnp.float32),
-        wire_req_words=jnp.zeros((), jnp.float32),
-        wire_reply_words=jnp.zeros((), jnp.float32),
-    )
+    # each lane's supersteps hand their counts out as scan outputs
+    parts: dict[str, list] = {k: [] for k in STAT_KEYS}
+    parts["stream_dropped"].append(dropped[None])
+
+    def collect(ys):
+        for k, v in ys.items():
+            parts[k].append(v.reshape(-1))
 
     # measured wire volume of one superstep: every slot (including block
     # padding) that crosses the shard axis through the transport
-    push_step_words = float(push_exch.round_slots() * w_push)
+    push_step_words = jnp.int32(push_exch.round_slots() * w_push)
 
     # On the mesh lowering the superstep loops run as a double-buffered
     # pipeline: superstep t+1's wire (the scatter/gather collectives) is
     # issued before superstep t's fold, so XLA can overlap the next
     # transfer with the current answer/intersect/update. Fold t still
-    # consumes exactly wire t's output and the stats accumulate in the
-    # same order, so results and stats stay bitwise-identical to the
+    # consumes exactly wire t's output and emits the same per-superstep
+    # counts, so results and stats stay bitwise-identical to the
     # sequential stacked loop (tests/test_mesh.py; docs/mesh.md).
     pipelined = cfg.transport == "mesh"
 
     def push_wire(t):
-        qr = _gen_push_queries(gr, st, t, push_exch, spec,
-                               delta=cfg.delta)
-        qx = push_exch.scatter(qr)
-        qx = dict(qx, ok=push_exch.apply_recv_ok(qx["ok"]))
-        qx = jax.tree.map(lambda x: _constrain(x, cfg), qx)
-        return qx, qr["ok"].sum(dtype=jnp.float32)
+        with jax.named_scope("engine/push/wire"):
+            qr = _gen_push_queries(gr, st, t, push_exch, spec,
+                                   delta=cfg.delta)
+            qx = push_exch.scatter(qr)
+            qx = dict(qx, ok=push_exch.apply_recv_ok(qx["ok"]))
+            qx = jax.tree.map(lambda x: _constrain(x, cfg), qx)
+            return qx, qr["ok"].sum(dtype=jnp.int32)
 
-    def push_fold(state, stats, qx, n_gen):
-        tri = _answer_push_queries(gr, qx, cfg, spec)
-        state = jax.vmap(survey.update)(state, tri)
-        stats = dict(stats)
-        stats["wedges_pushed"] += n_gen
-        stats["tris_push"] += tri.valid.sum(dtype=jnp.float32)
-        stats["wire_push_words"] += push_step_words
-        return state, stats
+    def push_fold(state, qx, n_gen):
+        with jax.named_scope("engine/push/check"):
+            tri = _answer_push_queries(gr, qx, cfg, spec)
+            n_tri = tri.valid.sum(dtype=jnp.int32)
+        with jax.named_scope("engine/fold"):
+            state = jax.vmap(survey.update)(state, tri)
+        return state, dict(wedges_pushed=n_gen, tris_push=n_tri,
+                           wire_push_words=push_step_words)
 
     if pipelined and cfg.n_push_steps > 0:
         qx, n_gen = push_wire(jnp.int32(0))
 
         def push_pipe(carry, t):
-            state, stats, qx, n_gen = carry
+            state, qx, n_gen = carry
             qx2, n_gen2 = push_wire(t + 1)   # wire t+1 before fold t
-            state, stats = push_fold(state, stats, qx, n_gen)
-            return (state, stats, qx2, n_gen2), None
+            state, ys = push_fold(state, qx, n_gen)
+            return (state, qx2, n_gen2), ys
 
         if cfg.n_push_steps > 1:
-            (state, stats, qx, n_gen), _ = jax.lax.scan(
-                push_pipe, (state, stats, qx, n_gen),
+            (state, qx, n_gen), ys = jax.lax.scan(
+                push_pipe, (state, qx, n_gen),
                 jnp.arange(cfg.n_push_steps - 1, dtype=jnp.int32),
                 unroll=(cfg.n_push_steps - 1) if cfg.unroll_steps else 1)
-        state, stats = push_fold(state, stats, qx, n_gen)
+            collect(ys)
+        state, ys = push_fold(state, qx, n_gen)
+        collect(ys)
     else:
-        def push_step(carry, t):
-            state, stats = carry
+        def push_step(state, t):
             qx, n_gen = push_wire(t)
-            state, stats = push_fold(state, stats, qx, n_gen)
-            return (state, stats), None
+            return push_fold(state, qx, n_gen)
 
-        (state, stats), _ = jax.lax.scan(
-            push_step, (state, stats),
+        state, ys = jax.lax.scan(
+            push_step, state,
             jnp.arange(cfg.n_push_steps, dtype=jnp.int32),
             unroll=cfg.n_push_steps if cfg.unroll_steps else 1)
+        collect(ys)
 
     if hub_on:
-        def hub_step(carry, t):
-            state, stats = carry
-            tri, n_w = _hub_superstep(gr, hst, t, cfg, spec)
-            state = jax.vmap(survey.update)(state, tri)
-            stats = dict(stats)
-            stats["wedges_hub"] += n_w.sum()
-            stats["tris_hub"] += tri.valid.sum(dtype=jnp.float32)
-            return (state, stats), None
+        def hub_step(state, t):
+            with jax.named_scope("engine/hub"):
+                tri, n_w = _hub_superstep(gr, hst, t, cfg, spec)
+                ys = dict(wedges_hub=n_w.sum(),
+                          tris_hub=tri.valid.sum(dtype=jnp.int32))
+            with jax.named_scope("engine/fold"):
+                state = jax.vmap(survey.update)(state, tri)
+            return state, ys
 
-        (state, stats), _ = jax.lax.scan(
-            hub_step, (state, stats),
+        state, ys = jax.lax.scan(
+            hub_step, state,
             jnp.arange(cfg.n_hub_steps, dtype=jnp.int32),
             unroll=cfg.n_hub_steps if cfg.unroll_steps else 1)
+        collect(ys)
 
     if cfg.mode == "pushpull" and cfg.n_pull_steps > 0:
         Lr = cfg.pull_row_cap if cfg.pull_row_cap else gr.d_plus_max
-        req_step_words = float(pull_exch.round_slots() * w_req)
-        reply_step_words = float(pull_exch.round_slots() * (w_hdr + Lr * w_row))
+        req_step_words = jnp.int32(pull_exch.round_slots() * w_req)
+        reply_step_words = jnp.int32(
+            pull_exch.round_slots() * (w_hdr + Lr * w_row))
 
-        def pull_fold(state, stats, t, rep, n_req):
-            state, tris, checked, overflow = _pull_compute(
+        def pull_wire(t):
+            with jax.named_scope("engine/pull/wire"):
+                return _pull_wire(gr, ps, t, cfg, spec, pull_exch)
+
+        def pull_fold(state, t, rep, n_req):
+            state, tris, checked, overflow, slots = _pull_compute(
                 gr, ps, t, cfg, spec, pull_exch, rep, survey, state)
-            stats = dict(stats)
-            stats["wedges_pulled"] += checked.sum(dtype=jnp.float32)
-            stats["tris_pull"] += tris.sum(dtype=jnp.float32)
-            stats["pull_requests"] += n_req
-            stats["pull_overflow"] += overflow.sum(dtype=jnp.float32)
-            stats["wire_req_words"] += req_step_words
-            stats["wire_reply_words"] += reply_step_words
-            return state, stats
+            return state, dict(
+                wedges_pulled=checked.sum(dtype=jnp.int32),
+                tris_pull=tris.sum(dtype=jnp.int32),
+                pull_requests=n_req,
+                pull_overflow=overflow.sum(dtype=jnp.int32),
+                wire_req_words=req_step_words,
+                wire_reply_words=reply_step_words,
+                pull_tile_slots=slots)
 
         if pipelined:
-            rep, n_req = _pull_wire(gr, ps, jnp.int32(0), cfg, spec,
-                                    pull_exch)
+            rep, n_req = pull_wire(jnp.int32(0))
 
             def pull_pipe(carry, t):
-                state, stats, rep, n_req = carry
-                rep2, n_req2 = _pull_wire(gr, ps, t + 1, cfg, spec,
-                                          pull_exch)   # wire t+1 ...
-                state, stats = pull_fold(state, stats, t, rep, n_req)
-                return (state, stats, rep2, n_req2), None   # ... fold t
+                state, rep, n_req = carry
+                rep2, n_req2 = pull_wire(t + 1)            # wire t+1 ...
+                state, ys = pull_fold(state, t, rep, n_req)
+                return (state, rep2, n_req2), ys           # ... fold t
 
             if cfg.n_pull_steps > 1:
-                (state, stats, rep, n_req), _ = jax.lax.scan(
-                    pull_pipe, (state, stats, rep, n_req),
+                (state, rep, n_req), ys = jax.lax.scan(
+                    pull_pipe, (state, rep, n_req),
                     jnp.arange(cfg.n_pull_steps - 1, dtype=jnp.int32),
                     unroll=(cfg.n_pull_steps - 1) if cfg.unroll_steps else 1)
-            state, stats = pull_fold(
-                state, stats, jnp.int32(cfg.n_pull_steps - 1), rep, n_req)
+                collect(ys)
+            state, ys = pull_fold(
+                state, jnp.int32(cfg.n_pull_steps - 1), rep, n_req)
+            collect(ys)
         else:
-            def pull_step(carry, t):
-                state, stats = carry
-                rep, n_req = _pull_wire(gr, ps, t, cfg, spec, pull_exch)
-                return pull_fold(state, stats, t, rep, n_req), None
+            def pull_step(state, t):
+                rep, n_req = pull_wire(t)
+                return pull_fold(state, t, rep, n_req)
 
-            (state, stats), _ = jax.lax.scan(
-                pull_step, (state, stats),
+            state, ys = jax.lax.scan(
+                pull_step, state,
                 jnp.arange(cfg.n_pull_steps, dtype=jnp.int32),
                 unroll=cfg.n_pull_steps if cfg.unroll_steps else 1)
+            collect(ys)
 
+    stats = {k: (jnp.concatenate(v) if v else jnp.zeros((1,), jnp.int32))
+             for k, v in parts.items()}
     return state, stats
 
 
@@ -1034,9 +1068,12 @@ def make_survey_fn(survey: Survey, cfg: EngineConfig, mesh=None):
     ``all_to_all`` for uniform caps, ``ppermute`` rotation rounds for
     ragged). Survey results are bitwise-identical to the stacked path:
     the per-device recv buffers are compacted to the exact stacked layout,
-    per-shard state is restacked before ``survey.merge``, and all counted
-    stats are integer-valued f32 so the split reduction is exact
-    (tests/test_mesh.py asserts all of this; docs/mesh.md explains it).
+    per-shard state is restacked before ``survey.merge``, and every
+    counted stat is an int32 count per superstep, summed over devices per
+    superstep and over supersteps on the host (:func:`sum_stats`), so the
+    split reduction is exact (tests/test_mesh.py asserts all of this;
+    docs/mesh.md explains it). ``pull_tile_slots`` counts what each
+    lowering staged, so it is the one stat the two lowerings may differ on.
     """
     if mesh is None:
         if cfg.transport == "mesh":
@@ -1053,7 +1090,8 @@ def make_survey_fn(survey: Survey, cfg: EngineConfig, mesh=None):
                          if cfg.mode == "pushpull" else None)
             state, stats = _survey_body(gr, survey, cfg, spec, push_exch,
                                         pull_exch)
-            return survey.merge(state), stats
+            with jax.named_scope("engine/fold"):
+                return survey.merge(state), stats
 
         return run
 
@@ -1093,7 +1131,8 @@ def make_survey_fn(survey: Survey, cfg: EngineConfig, mesh=None):
         state, stats = sm(gr)
         stats = {k: (v[0] if k in _WIRE_STAT_KEYS else v.sum(0))
                  for k, v in stats.items()}
-        return survey.merge(state), stats
+        with jax.named_scope("engine/fold"):
+            return survey.merge(state), stats
 
     return run
 
@@ -1114,13 +1153,13 @@ def resolve_survey_spec(survey: Survey, gr: ShardedDODGr,
 def _exactness_guard(cfg: EngineConfig, stats: dict) -> dict:
     """Satellite: a static window that overflowed means triangles were
     silently dropped — flag the run inexact, and say so loudly."""
-    lost = stats.get("pull_overflow", 0.0) + stats.get("stream_dropped", 0.0)
-    stats["exact"] = lost == 0.0
+    lost = stats.get("pull_overflow", 0) + stats.get("stream_dropped", 0)
+    stats["exact"] = lost == 0
     if lost > 0:
         msg = (
-            f"survey result is INEXACT: {int(stats.get('pull_overflow', 0))} "
+            f"survey result is INEXACT: {stats.get('pull_overflow', 0)} "
             f"pull-window candidate(s) and "
-            f"{int(stats.get('stream_dropped', 0))} stream slot(s) overflowed "
+            f"{stats.get('stream_dropped', 0)} stream slot(s) overflowed "
             "their static capacities and were dropped, so triangles are "
             "undercounted. Use the capacities planned by "
             "pushpull.plan_engine/plan_delta (they size every window "
@@ -1132,27 +1171,29 @@ def _exactness_guard(cfg: EngineConfig, stats: dict) -> dict:
 
 
 def _finalize_run(survey: Survey, cfg: EngineConfig, merged, stats):
-    """Host-side epilogue shared by the entry points: per-survey stats,
-    exactness guard, DOULION debiasing + its variance estimate
-    (Tsourakakis et al.)."""
-    stats = jax.tree.map(float, jax.device_get(stats))
-    members = getattr(survey, "surveys", (survey,))
-    stats["n_surveys"] = float(len(members))
-    stats = _exactness_guard(cfg, stats)
-    result = survey.finalize(merged)
-    if cfg.sample_p < 1.0:
-        p = cfg.sample_p
-        result = survey.scale_sampled(result, p)
-        raw = stats["tris_push"] + stats["tris_pull"] + stats["tris_hub"]
-        est = raw / p**3
-        # Var[T̂] ≈ T(1/p³ − 1) (independent-triangle term; the shared-edge
-        # covariance term needs the per-edge triangle multiset — see ref.py)
-        var = est * (1.0 / p**3 - 1.0)
-        stats["sample_p"] = p
-        stats["sample_scale"] = 1.0 / p**3
-        stats["sample_variance"] = var
-        stats["sample_rel_stderr"] = float(np.sqrt(var) / max(est, 1.0))
-    return result, stats
+    """Host-side epilogue shared by the entry points: per-survey stats
+    (exact integer totals), exactness guard, DOULION debiasing + its
+    variance estimate (Tsourakakis et al.)."""
+    with span("finalize"):
+        stats = sum_stats(stats)
+        members = getattr(survey, "surveys", (survey,))
+        stats["n_surveys"] = float(len(members))
+        stats = _exactness_guard(cfg, stats)
+        result = survey.finalize(merged)
+        if cfg.sample_p < 1.0:
+            p = cfg.sample_p
+            result = survey.scale_sampled(result, p)
+            raw = stats["tris_push"] + stats["tris_pull"] + stats["tris_hub"]
+            est = raw / p**3
+            # Var[T̂] ≈ T(1/p³ − 1) (independent-triangle term; the
+            # shared-edge covariance term needs the per-edge triangle
+            # multiset — see ref.py)
+            var = est * (1.0 / p**3 - 1.0)
+            stats["sample_p"] = p
+            stats["sample_scale"] = 1.0 / p**3
+            stats["sample_variance"] = var
+            stats["sample_rel_stderr"] = float(np.sqrt(var) / max(est, 1.0))
+        return result, stats
 
 
 def _check_sampling(gr: ShardedDODGr, cfg: EngineConfig) -> list[str]:
@@ -1277,7 +1318,7 @@ def survey_delta(gr: ShardedDODGr, survey: Survey, cfg: EngineConfig,
             RuntimeWarning, stacklevel=2)
     fn = jax.jit(make_survey_fn(survey, cfg, mesh=mesh))
     merged, stats = fn(gr)
-    stats = jax.tree.map(float, jax.device_get(stats))
+    stats = sum_stats(stats)
     stats["epoch"] = float(cfg.epoch)
     stats["n_surveys"] = float(len(getattr(survey, "surveys", (survey,))))
     stats = _exactness_guard(cfg, stats)

@@ -50,7 +50,7 @@ from repro.core.dodgr import (delta_gen_mask, hub_widths, meta_widths,
 from repro.core.engine import EngineConfig
 from repro.core.surveys import MetaSpec, Survey
 from repro.graphs.csr import DeltaGraph, HostGraph
-from repro.utils import bucket_cap, bucket_caps, bucket_floor, ceil_div
+from repro.utils import bucket_cap, bucket_caps, bucket_floor, ceil_div, span
 
 __all__ = [
     "VolumeReport", "plan_engine", "plan_delta", "plan_content_key",
@@ -550,396 +550,404 @@ def plan_engine(
     comparable — when the plan structure differs (mode, transport,
     resolved hub θ, delta-ness, projected widths, or shard count).
     """
-    if transport not in TRANSPORTS:
-        raise ValueError(f"transport must be one of {TRANSPORTS}, "
-                         f"got {transport!r}")
-    if cap_policy not in ("exact", "bucket"):
-        raise ValueError(f"cap_policy must be 'exact' or 'bucket', "
-                         f"got {cap_policy!r}")
-    bucket = cap_policy == "bucket"
-    g = sparsify_edges(g, sample_p, sample_seed)
-    sample_p, sample_seed = g.sample_p, g.sample_seed
-    delta = edge_new is not None
-    p, q, deg, h = orient_edges(g, orient)
-    d_plus = np.bincount(p, minlength=g.n).astype(np.int64)
-    s = (p % S).astype(np.int64)
-    d = (q % S).astype(np.int64)
-    local = p // S
-    n_loc = ceil_div(g.n, S)
+    with span("plan_engine"):
+        if transport not in TRANSPORTS:
+            raise ValueError(f"transport must be one of {TRANSPORTS}, "
+                             f"got {transport!r}")
+        if cap_policy not in ("exact", "bucket"):
+            raise ValueError(f"cap_policy must be 'exact' or 'bucket', "
+                             f"got {cap_policy!r}")
+        bucket = cap_policy == "bucket"
+        with span("plan_engine.orientation"):
+            g = sparsify_edges(g, sample_p, sample_seed)
+            sample_p, sample_seed = g.sample_p, g.sample_seed
+            delta = edge_new is not None
+            p, q, deg, h = orient_edges(g, orient)
+            d_plus = np.bincount(p, minlength=g.n).astype(np.int64)
+            s = (p % S).astype(np.int64)
+            d = (q % S).astype(np.int64)
+            local = p // S
+            n_loc = ceil_div(g.n, S)
 
-    # per-edge suffix length, identical to device: sort edges by
-    # (owner, local row, key(q)); suffix = row_len - pos_in_row - 1
-    order = np.lexsort((q, h[q], deg[q], local, s))
-    p_o, q_o, s_o, d_o = p[order], q[order], s[order], d[order]
-    row_key = s_o * n_loc + local[order]
-    _, row_start, row_len = np.unique(row_key, return_index=True, return_counts=True)
-    pos = np.arange(len(p_o)) - np.repeat(row_start, row_len)
-    suffix = (np.repeat(row_len, row_len) - pos - 1).astype(np.int64)
+            # per-edge suffix length, identical to device: sort edges by
+            # (owner, local row, key(q)); suffix = row_len - pos_in_row - 1
+            order = np.lexsort((q, h[q], deg[q], local, s))
+            p_o, q_o, s_o, d_o = p[order], q[order], s[order], d[order]
+            row_key = s_o * n_loc + local[order]
+            _, row_start, row_len = np.unique(row_key, return_index=True,
+                                              return_counts=True)
+            pos = np.arange(len(p_o)) - np.repeat(row_start, row_len)
+            suffix = (np.repeat(row_len, row_len) - pos - 1).astype(np.int64)
 
-    if delta:
-        new_o = np.asarray(edge_new, bool)[order]
-        touched = np.zeros(g.n, bool)
-        touched[g.src[edge_new]] = True
-        touched[g.dst[edge_new]] = True
-        gen = delta_gen_mask(q_o, row_start, row_len, new_o, touched)
-        suffix_w = suffix * gen
-    else:
-        suffix_w = suffix
+            if delta:
+                new_o = np.asarray(edge_new, bool)[order]
+                touched = np.zeros(g.n, bool)
+                touched[g.src[edge_new]] = True
+                touched[g.dst[edge_new]] = True
+                gen = delta_gen_mask(q_o, row_start, row_len, new_o, touched)
+                suffix_w = suffix * gen
+            else:
+                suffix_w = suffix
 
-    rspec = _resolve_plan_spec(survey, g)
-    w_push, w_row, w_hdr, w_req = meta_widths(*rspec.lane_counts())
-    if delta:
-        # on-wire newness: (pq_new, pr_new) bits on each push entry, r_new
-        # on each pulled row — one packed word apiece
-        w_push += 1
-        w_row += 1
-    full_spec = MetaSpec.full().resolve(g.spec.dvi, g.spec.dvf,
-                                        g.spec.dei, g.spec.def_)
-    w_push_full, w_row_full, _, _ = meta_widths(*full_spec.lane_counts())
+        with span("plan_engine.pull_decision"):
+            rspec = _resolve_plan_spec(survey, g)
+            w_push, w_row, w_hdr, w_req = meta_widths(*rspec.lane_counts())
+            if delta:
+                # on-wire newness: (pq_new, pr_new) bits on each push entry, r_new
+                # on each pulled row — one packed word apiece
+                w_push += 1
+                w_row += 1
+            full_spec = MetaSpec.full().resolve(g.spec.dvi, g.spec.dvf,
+                                                g.spec.dei, g.spec.def_)
+            w_push_full, w_row_full, _, _ = meta_widths(*full_spec.lane_counts())
 
-    # vol(s, q) and the pull decision (paper's inequality), over the wedges
-    # this plan will actually generate
-    sq = s_o * np.int64(g.n) + q_o
-    uq, inv = np.unique(sq, return_inverse=True)
-    vol = np.bincount(inv, weights=suffix_w).astype(np.int64)
-    gv = (uq % np.int64(g.n)).astype(np.int64)
-    dq_of_group = d_plus[gv]
-    if mode == "push":
-        base_pull = np.zeros(len(uq), bool)
-    elif cost_model == "entries":
-        base_pull = dq_of_group < vol
-    else:
-        base_pull = dq_of_group * w_row + w_hdr + w_req < vol * w_push
+            # vol(s, q) and the pull decision (paper's inequality), over the wedges
+            # this plan will actually generate
+            sq = s_o * np.int64(g.n) + q_o
+            uq, inv = np.unique(sq, return_inverse=True)
+            vol = np.bincount(inv, weights=suffix_w).astype(np.int64)
+            gv = (uq % np.int64(g.n)).astype(np.int64)
+            dq_of_group = d_plus[gv]
+            if mode == "push":
+                base_pull = np.zeros(len(uq), bool)
+            elif cost_model == "entries":
+                base_pull = dq_of_group < vol
+            else:
+                base_pull = dq_of_group * w_row + w_hdr + w_req < vol * w_push
 
-    # --- hub delegation: θ from the degree histogram + bytes cost model ---
-    w_hub_elem, w_hub_hdr = hub_widths(g.spec.dvi, g.spec.dvf, g.spec.dei,
-                                       g.spec.def_, delta=delta)
-    tdeg = (deg if orient == "degree" else g.degrees()).astype(np.int64)
-    theta = 0
-    if hub_theta == "auto":
-        # per-vertex wire load under the baseline plan: pushed wedge volume
-        # and pulled-group count — delegation erases exactly these, and
-        # removing the heaviest pulled rows also shrinks the reply padding
-        vol_push_v = np.bincount(gv[~base_pull], weights=vol[~base_pull],
-                                 minlength=g.n).astype(np.int64)
-        req_v = np.bincount(gv[base_pull], minlength=g.n).astype(np.int64)
-        theta = _choose_hub_theta(tdeg, d_plus, vol_push_v, req_v,
-                                  (w_push, w_row, w_hdr, w_req), S,
-                                  w_hub_elem, w_hub_hdr, max_hubs)
-    elif hub_theta:
-        theta = int(hub_theta)
-        if theta < 1:
-            raise ValueError(f"hub_theta must be ≥ 1 (or 0/'auto'), "
-                             f"got {theta}")
+        with span("plan_engine.hub_theta"):
+            # --- hub delegation: θ from the degree histogram + bytes cost model ---
+            w_hub_elem, w_hub_hdr = hub_widths(g.spec.dvi, g.spec.dvf, g.spec.dei,
+                                               g.spec.def_, delta=delta)
+            tdeg = (deg if orient == "degree" else g.degrees()).astype(np.int64)
+            theta = 0
+            if hub_theta == "auto":
+                # per-vertex wire load under the baseline plan: pushed wedge volume
+                # and pulled-group count — delegation erases exactly these, and
+                # removing the heaviest pulled rows also shrinks the reply padding
+                vol_push_v = np.bincount(gv[~base_pull], weights=vol[~base_pull],
+                                         minlength=g.n).astype(np.int64)
+                req_v = np.bincount(gv[base_pull], minlength=g.n).astype(np.int64)
+                theta = _choose_hub_theta(tdeg, d_plus, vol_push_v, req_v,
+                                          (w_push, w_row, w_hdr, w_req), S,
+                                          w_hub_elem, w_hub_hdr, max_hubs)
+            elif hub_theta:
+                theta = int(hub_theta)
+                if theta < 1:
+                    raise ValueError(f"hub_theta must be ≥ 1 (or 0/'auto'), "
+                                     f"got {theta}")
 
-    # session shape hysteresis: the previous epoch's caps are floors, but
-    # only within one plan structure — a different mode/transport/θ/width
-    # (or policy, or shard count) resets the mark to this plan alone
-    prev = promote_from if bucket else None
-    if prev is not None and not (
-            prev.cap_policy == "bucket" and prev.mode == mode
-            and prev.transport == transport and prev.delta == delta
-            and prev.hub_theta == theta
-            and prev.meta_widths == (w_push, w_row, w_hdr, w_req)
-            and (prev.push_caps is None or len(prev.push_caps) == S)):
-        prev = None
+            if theta >= 1:
+                hub_v = tdeg >= theta
+                n_hubs = int(hub_v.sum())
+                hub_e = hub_v[q_o]
+                pull_group = base_pull & ~hub_v[gv]
+                hub_table_bytes = int(S * (d_plus[hub_v] * w_hub_elem
+                                           + w_hub_hdr).sum()) * 4
+            else:
+                n_hubs = 0
+                hub_e = np.zeros(len(q_o), bool)
+                pull_group = base_pull
+                hub_table_bytes = 0
+        with span("plan_engine.caps"):
+            # session shape hysteresis: the previous epoch's caps are floors, but
+            # only within one plan structure — a different mode/transport/θ/width
+            # (or policy, or shard count) resets the mark to this plan alone
+            prev = promote_from if bucket else None
+            if prev is not None and not (
+                    prev.cap_policy == "bucket" and prev.mode == mode
+                    and prev.transport == transport and prev.delta == delta
+                    and prev.hub_theta == theta
+                    and prev.meta_widths == (w_push, w_row, w_hdr, w_req)
+                    and (prev.push_caps is None or len(prev.push_caps) == S)):
+                prev = None
 
-    if theta >= 1:
-        hub_v = tdeg >= theta
-        n_hubs = int(hub_v.sum())
-        hub_e = hub_v[q_o]
-        pull_group = base_pull & ~hub_v[gv]
-        hub_table_bytes = int(S * (d_plus[hub_v] * w_hub_elem
-                                   + w_hub_hdr).sum()) * 4
-    else:
-        n_hubs = 0
-        hub_e = np.zeros(len(q_o), bool)
-        pull_group = base_pull
-        hub_table_bytes = 0
-    pull_e = pull_group[inv]
-    push_e = ~pull_e & ~hub_e
+            pull_e = pull_group[inv]
+            push_e = ~pull_e & ~hub_e
 
-    wedges_total = int(suffix.sum())
-    gen_wedges = int(suffix_w.sum())
-    hub_w = suffix_w * hub_e
-    hub_resolved = int(hub_w.sum())
-    hub_per_shard = np.bincount(s_o, weights=hub_w, minlength=S)
-    if bucket:
-        hub_wedge_cap = bucket_cap(hub_wedge_cap)
-        if prev is not None:
-            hub_wedge_cap = max(hub_wedge_cap, prev.hub_wedge_cap)
-    n_hub_steps = (ceil_div(int(hub_per_shard.max()), hub_wedge_cap)
-                   if hub_resolved else 0)
-    if bucket:
-        n_hub_steps = bucket_cap(n_hub_steps)
-        if prev is not None:
-            # extra hub supersteps only scan empty (masked) wedge slots
-            n_hub_steps = max(n_hub_steps, prev.n_hub_steps)
-
-    pushed = suffix_w[push_e]
-    sd = s_o * S + d_o
-    push_stream = np.bincount(sd[push_e], weights=pushed, minlength=S * S)
-    max_push_stream = int(push_stream.max()) if len(push_stream) else 0
-    # exact-policy lane shape, always derived: the report stamps it next
-    # to the (possibly bucketed) primary values so the padding is auditable
-    exact_n_push_steps = max(1, ceil_div(max_push_stream, push_cap))
-    if transport in ("ragged", "mesh"):
-        exact_push_slots = int(
-            (-(-push_stream.astype(np.int64) // exact_n_push_steps)).sum())
-    else:
-        exact_push_slots = S * S * push_cap
-    if bucket:
-        push_cap = bucket_cap(push_cap)
-        if prev is not None:
-            push_cap = max(push_cap, prev.push_cap)
-    n_push_steps = max(1, ceil_div(max_push_stream, push_cap))
-    if bucket:
-        n_push_steps = bucket_cap(n_push_steps)
-        if prev is not None:
-            n_push_steps = max(n_push_steps, prev.n_push_steps)
-    push_caps = None
-    if transport in ("ragged", "mesh"):
-        # per-pair caps derive from the already-promoted step count, so
-        # n_steps × cap still covers each pair's stream; the push lane's
-        # window width equals its slot count, so raising either is pure
-        # masked padding (unlike the pull lane's edge windows below)
-        pc = -(-push_stream.astype(np.int64) // n_push_steps)
-        if bucket:
-            pc = bucket_caps(pc)
-            if prev is not None and prev.push_caps is not None:
-                pc = np.maximum(
-                    pc, np.asarray(prev.push_caps, np.int64).reshape(-1))
-        push_caps = tuple(tuple(int(x) for x in row)
-                          for row in pc.reshape(S, S))
-
-    # pulled groups per (s, d) → pull supersteps; edge windows → edge cap
-    n_pull_steps = 0
-    pull_edge_cap = 1
-    pull_caps = None
-    pull_row_cap = 0
-    pull_groups_max = 0
-    exact_pull_row_cap = 0
-    exact_pull_q_cap = int(pull_q_cap) if pull_q_cap is not None else 0
-    exact_n_pull_steps = 0
-    exact_req_slots = 0
-    n_pulled_groups = int(pull_group.sum())
-    if mode == "pushpull" and n_pulled_groups:
-        g_s = (uq // np.int64(g.n))[pull_group]
-        g_q = (uq % np.int64(g.n))[pull_group]
-        g_d = g_q % S
-        # reply rows pad to the heaviest row actually pulled — under hub
-        # delegation the heavy rows left the pull set, so this (and the
-        # dominant reply volume) shrinks to the heaviest survivor
-        exact_pull_row_cap = max(1, int(d_plus[g_q].max()))
-        pull_row_cap = (bucket_cap(exact_pull_row_cap) if bucket
-                        else exact_pull_row_cap)
-        if prev is not None:
-            pull_row_cap = max(pull_row_cap, prev.pull_row_cap)
-        per_sd = np.bincount(g_s * S + g_d, minlength=S * S)
-        pull_groups_max = int(per_sd.max())
-        if pull_q_cap is None:
-            exact_pull_q_cap = _autotune_pull_q_cap(per_sd, w_row, w_hdr,
-                                                    exact_pull_row_cap)
-            # the bucket=True autotune is already on-grid within the
-            # reply-window byte bound — re-rounding up here would breach it
-            pull_q_cap = (_autotune_pull_q_cap(per_sd, w_row, w_hdr,
-                                               pull_row_cap, bucket=True)
-                          if bucket else exact_pull_q_cap)
-        elif bucket:
-            pull_q_cap = bucket_cap(int(pull_q_cap))
-        if prev is not None:
-            pull_q_cap = max(pull_q_cap, prev.pull_q_cap)
-        exact_n_pull_steps = max(1, ceil_div(pull_groups_max,
-                                             exact_pull_q_cap))
-        n_pull_steps = max(1, ceil_div(pull_groups_max, pull_q_cap))
-        if bucket:
-            n_pull_steps = bucket_cap(n_pull_steps)
-            if prev is not None:
-                n_pull_steps = max(n_pull_steps, prev.n_pull_steps)
-        if transport in ("ragged", "mesh"):
-            exact_req_slots = int(
-                (-(-per_sd.astype(np.int64) // exact_n_pull_steps)).sum())
-            pc = -(-per_sd.astype(np.int64) // n_pull_steps)
+            wedges_total = int(suffix.sum())
+            gen_wedges = int(suffix_w.sum())
+            hub_w = suffix_w * hub_e
+            hub_resolved = int(hub_w.sum())
+            hub_per_shard = np.bincount(s_o, weights=hub_w, minlength=S)
             if bucket:
-                pc = bucket_caps(pc)
-                if prev is not None and prev.pull_caps is not None:
-                    pc = np.maximum(
-                        pc, np.asarray(prev.pull_caps, np.int64).reshape(-1))
-            pull_caps = tuple(tuple(int(x) for x in row)
-                              for row in pc.reshape(S, S))
-            caps_of_sd = pc
-        else:
-            exact_req_slots = S * S * exact_pull_q_cap
-            caps_of_sd = np.full(S * S, pull_q_cap, np.int64)
-        # edges per (s,d,window): group rank within (s,d) in (q) order,
-        # window = rank // cap(s,d); edge count per window
-        grp_order = np.lexsort((g_q, g_d, g_s))
-        gsd = (g_s * S + g_d)[grp_order]
-        rank_in_sd = np.arange(len(gsd)) - np.searchsorted(gsd, gsd, side="left")
-        win = rank_in_sd // np.maximum(caps_of_sd[gsd], 1)
-        # map each pulled edge to its group's window
-        grp_win = np.empty(len(uq), np.int64)
-        pulled_idx = np.nonzero(pull_group)[0]
-        grp_win_vals = np.empty(len(gsd), np.int64)
-        grp_win_vals[grp_order] = win
-        grp_win[pulled_idx] = grp_win_vals
-        e_win = grp_win[inv[pull_e]]
-        e_sd = sd[pull_e]
-        key = e_sd * (int(win.max()) + 1 if len(win) else 1) + e_win
-        per_window = np.bincount(key)
-        # the window partition above used the policy-resolved (and, under
-        # hysteresis, promoted) caps, so the edge windows the engine
-        # executes match — this is why promotion lives in the planner:
-        # pull_edge_cap is only valid for the exact caps_of_sd it was
-        # measured under. The cap itself buckets (and promotes) like
-        # every other shape knob: raising it only widens masked slots.
-        pull_edge_cap = max(1, int(per_window.max()))
-        if bucket:
-            pull_edge_cap = bucket_cap(pull_edge_cap)
-            if prev is not None:
-                pull_edge_cap = max(pull_edge_cap, prev.pull_edge_cap)
-    if pull_q_cap is None:
-        pull_q_cap = 32  # nothing pulled — any cap is a no-op
-        exact_pull_q_cap = 32
-    elif bucket:
-        pull_q_cap = bucket_cap(int(pull_q_cap))
-    if (prev is not None and mode == "pushpull" and not n_pulled_groups
-            and prev.n_pull_steps):
-        # nothing pulled this epoch but the session shape has a pull lane:
-        # adopt it wholesale — every window scans zero groups, so the
-        # promoted lane is pure masked padding and the shape signature
-        # (hence the executable) repeats
-        pull_q_cap = max(pull_q_cap, prev.pull_q_cap)
-        n_pull_steps = prev.n_pull_steps
-        pull_edge_cap = max(pull_edge_cap, prev.pull_edge_cap)
-        pull_row_cap = max(pull_row_cap, prev.pull_row_cap)
-        if prev.pull_caps is not None:
-            pull_caps = prev.pull_caps
-    if transport in ("ragged", "mesh") and pull_caps is None:
-        pull_caps = tuple((0,) * S for _ in range(S))
+                hub_wedge_cap = bucket_cap(hub_wedge_cap)
+                if prev is not None:
+                    hub_wedge_cap = max(hub_wedge_cap, prev.hub_wedge_cap)
+            n_hub_steps = (ceil_div(int(hub_per_shard.max()), hub_wedge_cap)
+                           if hub_resolved else 0)
+            if bucket:
+                n_hub_steps = bucket_cap(n_hub_steps)
+                if prev is not None:
+                    # extra hub supersteps only scan empty (masked) wedge slots
+                    n_hub_steps = max(n_hub_steps, prev.n_hub_steps)
 
-    # --- volumes ---
-    push_only_entries = gen_wedges - hub_resolved
-    push_only_bytes = push_only_entries * w_push * 4 + hub_table_bytes
-    pp_push_entries = int(pushed.sum())
-    pp_rows = int(d_plus[(uq % np.int64(g.n))[pull_group]].sum())
-    pp_bytes = (pp_push_entries * w_push + n_pulled_groups * (w_req + w_hdr)
-                + pp_rows * w_row) * 4 + hub_table_bytes
-    # --- transport wire volumes (buffer slots that actually cross shards,
-    # block padding included — must equal the engine's measured stats) ---
-    if transport in ("ragged", "mesh"):
-        push_slots = int(sum(sum(row) for row in push_caps))
-        req_slots = int(sum(sum(row) for row in pull_caps)) if pull_caps else 0
-    else:
-        push_slots = S * S * push_cap
-        req_slots = S * S * pull_q_cap if n_pull_steps else 0
-    wire_push_bytes = n_push_steps * push_slots * w_push * 4
-    wire_req_bytes = n_pull_steps * req_slots * w_req * 4
-    wire_reply_bytes = (n_pull_steps * req_slots
-                        * (w_hdr + pull_row_cap * w_row) * 4)
-    # exact-policy wire bytes (== the primary fields under cap_policy=
-    # "exact"): the bucket grid's padding tax is their difference — the
-    # cost model stays honest about what bucketing added to the wire
-    exact_wire_push_bytes = exact_n_push_steps * exact_push_slots * w_push * 4
-    exact_wire_req_bytes = exact_n_pull_steps * exact_req_slots * w_req * 4
-    exact_wire_reply_bytes = (exact_n_pull_steps * exact_req_slots
-                              * (w_hdr + exact_pull_row_cap * w_row) * 4)
-    bucket_pad_bytes = ((wire_push_bytes + wire_req_bytes + wire_reply_bytes)
-                        - (exact_wire_push_bytes + exact_wire_req_bytes
-                           + exact_wire_reply_bytes))
-    # --- mesh round schedule: the planner stamps the same deterministic
-    # schedule the transport will execute, so the report carries the
-    # physical wire structure (and the naive-rotation bound) per lane ---
-    sched = dict(sched_push_rounds=0, sched_push_slots=0,
-                 naive_push_rounds=0, naive_push_slots=0,
-                 sched_req_rounds=0, sched_req_slots=0,
-                 naive_req_rounds=0, naive_req_slots=0)
-    if transport == "mesh":
-        from repro.comm.round_schedule import best_schedule, rotation_schedule
-        for lane, caps_l in (("push", push_caps), ("req", pull_caps)):
-            if caps_l is None or (lane == "req" and not n_pull_steps):
-                continue
-            caps_a = np.asarray(caps_l, np.int64)
-            best = best_schedule(caps_a)
-            naive = rotation_schedule(caps_a)
-            sched[f"sched_{lane}_rounds"] = best.n_rounds
-            sched[f"sched_{lane}_slots"] = best.wire_slots
-            sched[f"naive_{lane}_rounds"] = naive.n_rounds
-            sched[f"naive_{lane}_slots"] = naive.wire_slots
-    report = VolumeReport(
-        S=S,
-        wedges_total=wedges_total,
-        push_only_entries=push_only_entries,
-        push_only_bytes=push_only_bytes,
-        pushpull_push_entries=pp_push_entries,
-        pushpull_pull_rows=pp_rows,
-        pushpull_requests=n_pulled_groups,
-        pushpull_bytes=pp_bytes if mode == "pushpull" else push_only_bytes,
-        pulls_per_rank=n_pulled_groups / S,
-        pulled_wedges=int(suffix_w[pull_e].sum()),
-        push_entry_width=w_push,
-        pull_row_width=w_row,
-        pull_header_width=w_hdr,
-        request_width=w_req,
-        full_push_entry_width=w_push_full,
-        full_pull_row_width=w_row_full,
-        gen_wedges=gen_wedges,
-        epoch=epoch,
-        pull_q_cap=pull_q_cap,
-        pull_row_cap=pull_row_cap,
-        transport=transport,
-        hub_theta=theta,
-        n_hubs=n_hubs,
-        hub_resolved_wedges=hub_resolved,
-        hub_table_bytes=hub_table_bytes,
-        wire_push_slots_step=push_slots,
-        wire_req_slots_step=req_slots,
-        wire_push_bytes=wire_push_bytes,
-        wire_req_bytes=wire_req_bytes,
-        wire_reply_bytes=wire_reply_bytes,
-        push_stream_max=max_push_stream,
-        pull_groups_max=pull_groups_max,
-        hub_stream_max=int(hub_per_shard.max()) if hub_resolved else 0,
-        cap_policy=cap_policy,
-        exact_n_push_steps=exact_n_push_steps,
-        exact_n_pull_steps=exact_n_pull_steps,
-        exact_pull_q_cap=exact_pull_q_cap,
-        exact_pull_row_cap=exact_pull_row_cap,
-        exact_wire_push_bytes=exact_wire_push_bytes,
-        exact_wire_req_bytes=exact_wire_req_bytes,
-        exact_wire_reply_bytes=exact_wire_reply_bytes,
-        bucket_pad_bytes=bucket_pad_bytes,
-        **sched,
-    )
-    cfg = EngineConfig(
-        mode=mode,
-        push_cap=push_cap,
-        n_push_steps=n_push_steps,
-        pull_q_cap=pull_q_cap,
-        pull_edge_cap=pull_edge_cap,
-        n_pull_steps=n_pull_steps,
-        pull_row_cap=pull_row_cap,
-        cost_model=cost_model,
-        use_pallas=use_pallas,
-        shard_axis=shard_axis,
-        sample_p=sample_p,
-        sample_seed=sample_seed,
-        meta_widths=(w_push, w_row, w_hdr, w_req),
-        delta=delta,
-        epoch=epoch,
-        orient=orient,
-        transport=transport,
-        push_caps=push_caps,
-        pull_caps=pull_caps,
-        hub_theta=theta,
-        n_hub_steps=n_hub_steps,
-        hub_wedge_cap=hub_wedge_cap,
-        on_overflow=on_overflow,
-        cap_policy=cap_policy,
-        determinism=_determinism_of(
-            survey, (g.spec.dvi, g.spec.dvf, g.spec.dei, g.spec.def_)),
-    )
-    return cfg, report
+            pushed = suffix_w[push_e]
+            sd = s_o * S + d_o
+            push_stream = np.bincount(sd[push_e], weights=pushed, minlength=S * S)
+            max_push_stream = int(push_stream.max()) if len(push_stream) else 0
+            # exact-policy lane shape, always derived: the report stamps it next
+            # to the (possibly bucketed) primary values so the padding is auditable
+            exact_n_push_steps = max(1, ceil_div(max_push_stream, push_cap))
+            if transport in ("ragged", "mesh"):
+                exact_push_slots = int(
+                    (-(-push_stream.astype(np.int64) // exact_n_push_steps)).sum())
+            else:
+                exact_push_slots = S * S * push_cap
+            if bucket:
+                push_cap = bucket_cap(push_cap)
+                if prev is not None:
+                    push_cap = max(push_cap, prev.push_cap)
+            n_push_steps = max(1, ceil_div(max_push_stream, push_cap))
+            if bucket:
+                n_push_steps = bucket_cap(n_push_steps)
+                if prev is not None:
+                    n_push_steps = max(n_push_steps, prev.n_push_steps)
+            push_caps = None
+            if transport in ("ragged", "mesh"):
+                # per-pair caps derive from the already-promoted step count, so
+                # n_steps × cap still covers each pair's stream; the push lane's
+                # window width equals its slot count, so raising either is pure
+                # masked padding (unlike the pull lane's edge windows below)
+                pc = -(-push_stream.astype(np.int64) // n_push_steps)
+                if bucket:
+                    pc = bucket_caps(pc)
+                    if prev is not None and prev.push_caps is not None:
+                        pc = np.maximum(
+                            pc, np.asarray(prev.push_caps, np.int64).reshape(-1))
+                push_caps = tuple(tuple(int(x) for x in row)
+                                  for row in pc.reshape(S, S))
+
+            # pulled groups per (s, d) → pull supersteps; edge windows → edge cap
+            n_pull_steps = 0
+            pull_edge_cap = 1
+            pull_caps = None
+            pull_row_cap = 0
+            pull_groups_max = 0
+            exact_pull_row_cap = 0
+            exact_pull_q_cap = int(pull_q_cap) if pull_q_cap is not None else 0
+            exact_n_pull_steps = 0
+            exact_req_slots = 0
+            n_pulled_groups = int(pull_group.sum())
+            if mode == "pushpull" and n_pulled_groups:
+                g_s = (uq // np.int64(g.n))[pull_group]
+                g_q = (uq % np.int64(g.n))[pull_group]
+                g_d = g_q % S
+                # reply rows pad to the heaviest row actually pulled — under hub
+                # delegation the heavy rows left the pull set, so this (and the
+                # dominant reply volume) shrinks to the heaviest survivor
+                exact_pull_row_cap = max(1, int(d_plus[g_q].max()))
+                pull_row_cap = (bucket_cap(exact_pull_row_cap) if bucket
+                                else exact_pull_row_cap)
+                if prev is not None:
+                    pull_row_cap = max(pull_row_cap, prev.pull_row_cap)
+                per_sd = np.bincount(g_s * S + g_d, minlength=S * S)
+                pull_groups_max = int(per_sd.max())
+                if pull_q_cap is None:
+                    exact_pull_q_cap = _autotune_pull_q_cap(per_sd, w_row, w_hdr,
+                                                            exact_pull_row_cap)
+                    # the bucket=True autotune is already on-grid within the
+                    # reply-window byte bound — re-rounding up here would breach it
+                    pull_q_cap = (_autotune_pull_q_cap(per_sd, w_row, w_hdr,
+                                                       pull_row_cap, bucket=True)
+                                  if bucket else exact_pull_q_cap)
+                elif bucket:
+                    pull_q_cap = bucket_cap(int(pull_q_cap))
+                if prev is not None:
+                    pull_q_cap = max(pull_q_cap, prev.pull_q_cap)
+                exact_n_pull_steps = max(1, ceil_div(pull_groups_max,
+                                                     exact_pull_q_cap))
+                n_pull_steps = max(1, ceil_div(pull_groups_max, pull_q_cap))
+                if bucket:
+                    n_pull_steps = bucket_cap(n_pull_steps)
+                    if prev is not None:
+                        n_pull_steps = max(n_pull_steps, prev.n_pull_steps)
+                if transport in ("ragged", "mesh"):
+                    exact_req_slots = int(
+                        (-(-per_sd.astype(np.int64) // exact_n_pull_steps)).sum())
+                    pc = -(-per_sd.astype(np.int64) // n_pull_steps)
+                    if bucket:
+                        pc = bucket_caps(pc)
+                        if prev is not None and prev.pull_caps is not None:
+                            pc = np.maximum(
+                                pc, np.asarray(prev.pull_caps, np.int64).reshape(-1))
+                    pull_caps = tuple(tuple(int(x) for x in row)
+                                      for row in pc.reshape(S, S))
+                    caps_of_sd = pc
+                else:
+                    exact_req_slots = S * S * exact_pull_q_cap
+                    caps_of_sd = np.full(S * S, pull_q_cap, np.int64)
+                # edges per (s,d,window): group rank within (s,d) in (q) order,
+                # window = rank // cap(s,d); edge count per window
+                grp_order = np.lexsort((g_q, g_d, g_s))
+                gsd = (g_s * S + g_d)[grp_order]
+                rank_in_sd = (np.arange(len(gsd))
+                              - np.searchsorted(gsd, gsd, side="left"))
+                win = rank_in_sd // np.maximum(caps_of_sd[gsd], 1)
+                # map each pulled edge to its group's window
+                grp_win = np.empty(len(uq), np.int64)
+                pulled_idx = np.nonzero(pull_group)[0]
+                grp_win_vals = np.empty(len(gsd), np.int64)
+                grp_win_vals[grp_order] = win
+                grp_win[pulled_idx] = grp_win_vals
+                e_win = grp_win[inv[pull_e]]
+                e_sd = sd[pull_e]
+                key = e_sd * (int(win.max()) + 1 if len(win) else 1) + e_win
+                per_window = np.bincount(key)
+                # the window partition above used the policy-resolved (and, under
+                # hysteresis, promoted) caps, so the edge windows the engine
+                # executes match — this is why promotion lives in the planner:
+                # pull_edge_cap is only valid for the exact caps_of_sd it was
+                # measured under. The cap itself buckets (and promotes) like
+                # every other shape knob: raising it only widens masked slots.
+                pull_edge_cap = max(1, int(per_window.max()))
+                if bucket:
+                    pull_edge_cap = bucket_cap(pull_edge_cap)
+                    if prev is not None:
+                        pull_edge_cap = max(pull_edge_cap, prev.pull_edge_cap)
+            if pull_q_cap is None:
+                pull_q_cap = 32  # nothing pulled — any cap is a no-op
+                exact_pull_q_cap = 32
+            elif bucket:
+                pull_q_cap = bucket_cap(int(pull_q_cap))
+            if (prev is not None and mode == "pushpull" and not n_pulled_groups
+                    and prev.n_pull_steps):
+                # nothing pulled this epoch but the session shape has a pull lane:
+                # adopt it wholesale — every window scans zero groups, so the
+                # promoted lane is pure masked padding and the shape signature
+                # (hence the executable) repeats
+                pull_q_cap = max(pull_q_cap, prev.pull_q_cap)
+                n_pull_steps = prev.n_pull_steps
+                pull_edge_cap = max(pull_edge_cap, prev.pull_edge_cap)
+                pull_row_cap = max(pull_row_cap, prev.pull_row_cap)
+                if prev.pull_caps is not None:
+                    pull_caps = prev.pull_caps
+            if transport in ("ragged", "mesh") and pull_caps is None:
+                pull_caps = tuple((0,) * S for _ in range(S))
+
+        with span("plan_engine.report"):
+            # --- volumes ---
+            push_only_entries = gen_wedges - hub_resolved
+            push_only_bytes = push_only_entries * w_push * 4 + hub_table_bytes
+            pp_push_entries = int(pushed.sum())
+            pp_rows = int(d_plus[(uq % np.int64(g.n))[pull_group]].sum())
+            pp_bytes = (pp_push_entries * w_push + n_pulled_groups * (w_req + w_hdr)
+                        + pp_rows * w_row) * 4 + hub_table_bytes
+            # --- transport wire volumes (buffer slots that actually cross shards,
+            # block padding included — must equal the engine's measured stats) ---
+            if transport in ("ragged", "mesh"):
+                push_slots = int(sum(sum(row) for row in push_caps))
+                req_slots = int(sum(sum(row) for row in pull_caps)) if pull_caps else 0
+            else:
+                push_slots = S * S * push_cap
+                req_slots = S * S * pull_q_cap if n_pull_steps else 0
+            wire_push_bytes = n_push_steps * push_slots * w_push * 4
+            wire_req_bytes = n_pull_steps * req_slots * w_req * 4
+            wire_reply_bytes = (n_pull_steps * req_slots
+                                * (w_hdr + pull_row_cap * w_row) * 4)
+            # exact-policy wire bytes (== the primary fields under cap_policy=
+            # "exact"): the bucket grid's padding tax is their difference — the
+            # cost model stays honest about what bucketing added to the wire
+            exact_wire_push_bytes = exact_n_push_steps * exact_push_slots * w_push * 4
+            exact_wire_req_bytes = exact_n_pull_steps * exact_req_slots * w_req * 4
+            exact_wire_reply_bytes = (exact_n_pull_steps * exact_req_slots
+                                      * (w_hdr + exact_pull_row_cap * w_row) * 4)
+            bucket_pad_bytes = ((wire_push_bytes + wire_req_bytes + wire_reply_bytes)
+                                - (exact_wire_push_bytes + exact_wire_req_bytes
+                                   + exact_wire_reply_bytes))
+            # --- mesh round schedule: the planner stamps the same deterministic
+            # schedule the transport will execute, so the report carries the
+            # physical wire structure (and the naive-rotation bound) per lane ---
+            sched = dict(sched_push_rounds=0, sched_push_slots=0,
+                         naive_push_rounds=0, naive_push_slots=0,
+                         sched_req_rounds=0, sched_req_slots=0,
+                         naive_req_rounds=0, naive_req_slots=0)
+            if transport == "mesh":
+                from repro.comm.round_schedule import best_schedule, rotation_schedule
+                for lane, caps_l in (("push", push_caps), ("req", pull_caps)):
+                    if caps_l is None or (lane == "req" and not n_pull_steps):
+                        continue
+                    caps_a = np.asarray(caps_l, np.int64)
+                    best = best_schedule(caps_a)
+                    naive = rotation_schedule(caps_a)
+                    sched[f"sched_{lane}_rounds"] = best.n_rounds
+                    sched[f"sched_{lane}_slots"] = best.wire_slots
+                    sched[f"naive_{lane}_rounds"] = naive.n_rounds
+                    sched[f"naive_{lane}_slots"] = naive.wire_slots
+            report = VolumeReport(
+                S=S,
+                wedges_total=wedges_total,
+                push_only_entries=push_only_entries,
+                push_only_bytes=push_only_bytes,
+                pushpull_push_entries=pp_push_entries,
+                pushpull_pull_rows=pp_rows,
+                pushpull_requests=n_pulled_groups,
+                pushpull_bytes=pp_bytes if mode == "pushpull" else push_only_bytes,
+                pulls_per_rank=n_pulled_groups / S,
+                pulled_wedges=int(suffix_w[pull_e].sum()),
+                push_entry_width=w_push,
+                pull_row_width=w_row,
+                pull_header_width=w_hdr,
+                request_width=w_req,
+                full_push_entry_width=w_push_full,
+                full_pull_row_width=w_row_full,
+                gen_wedges=gen_wedges,
+                epoch=epoch,
+                pull_q_cap=pull_q_cap,
+                pull_row_cap=pull_row_cap,
+                transport=transport,
+                hub_theta=theta,
+                n_hubs=n_hubs,
+                hub_resolved_wedges=hub_resolved,
+                hub_table_bytes=hub_table_bytes,
+                wire_push_slots_step=push_slots,
+                wire_req_slots_step=req_slots,
+                wire_push_bytes=wire_push_bytes,
+                wire_req_bytes=wire_req_bytes,
+                wire_reply_bytes=wire_reply_bytes,
+                push_stream_max=max_push_stream,
+                pull_groups_max=pull_groups_max,
+                hub_stream_max=int(hub_per_shard.max()) if hub_resolved else 0,
+                cap_policy=cap_policy,
+                exact_n_push_steps=exact_n_push_steps,
+                exact_n_pull_steps=exact_n_pull_steps,
+                exact_pull_q_cap=exact_pull_q_cap,
+                exact_pull_row_cap=exact_pull_row_cap,
+                exact_wire_push_bytes=exact_wire_push_bytes,
+                exact_wire_req_bytes=exact_wire_req_bytes,
+                exact_wire_reply_bytes=exact_wire_reply_bytes,
+                bucket_pad_bytes=bucket_pad_bytes,
+                **sched,
+            )
+            cfg = EngineConfig(
+                mode=mode,
+                push_cap=push_cap,
+                n_push_steps=n_push_steps,
+                pull_q_cap=pull_q_cap,
+                pull_edge_cap=pull_edge_cap,
+                n_pull_steps=n_pull_steps,
+                pull_row_cap=pull_row_cap,
+                cost_model=cost_model,
+                use_pallas=use_pallas,
+                shard_axis=shard_axis,
+                sample_p=sample_p,
+                sample_seed=sample_seed,
+                meta_widths=(w_push, w_row, w_hdr, w_req),
+                delta=delta,
+                epoch=epoch,
+                orient=orient,
+                transport=transport,
+                push_caps=push_caps,
+                pull_caps=pull_caps,
+                hub_theta=theta,
+                n_hub_steps=n_hub_steps,
+                hub_wedge_cap=hub_wedge_cap,
+                on_overflow=on_overflow,
+                cap_policy=cap_policy,
+                determinism=_determinism_of(
+                    survey, (g.spec.dvi, g.spec.dvf, g.spec.dei, g.spec.def_)),
+            )
+            return cfg, report
 
 
 def plan_delta(

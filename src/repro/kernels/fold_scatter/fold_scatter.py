@@ -113,6 +113,7 @@ def fold_count_max_pallas(slots, amounts, rows, capacity: int, bb: int = 256,
             jax.ShapeDtypeStruct((1, capacity), jnp.int32),
             jax.ShapeDtypeStruct((W, capacity), jnp.int32),
         ),
+        name="fold_count_max",
         interpret=interpret,
     )(slots, amounts, rows)
 
@@ -160,5 +161,6 @@ def ring_set_pallas(prior, slots, rows, capacity: int, bb: int = 256,
                   pl.BlockSpec((bb, W), lambda i, j: (j, 0))],
         out_specs=table,
         out_shape=jax.ShapeDtypeStruct((W, capacity), rows.dtype),
+        name="ring_set",
         interpret=interpret,
     )(prior, slots, rows)

@@ -45,6 +45,7 @@ def hist_add_pallas(slots, amounts, capacity: int, bb: int = 1024,
         in_specs=[col, col],
         out_specs=pl.BlockSpec((1, cap_tile), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, capacity), jnp.int32),
+        name="hist_add",
         interpret=interpret,
     )(slots, amounts)
 
@@ -82,5 +83,6 @@ def hist_max_pallas(slots, rows, capacity: int, bb: int = 256,
         ],
         out_specs=pl.BlockSpec((W, cap_tile), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((W, capacity), jnp.int32),
+        name="hist_max",
         interpret=interpret,
     )(slots, rows)

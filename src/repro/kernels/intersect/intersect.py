@@ -62,5 +62,6 @@ def intersect_pallas(row_d, row_h, row_i, ln, qd, qh, qi,
         in_specs=[mat, mat, mat, vec, mat, mat, mat],
         out_specs=mat,
         out_shape=jax.ShapeDtypeStruct((B, L), jnp.int32),
+        name="intersect",
         interpret=interpret,
     )(row_d, row_h, row_i, ln[:, None], qd, qh, qi)

@@ -64,5 +64,6 @@ def wedge_check_pallas(keys_d, keys_h, keys_i, lo, hi, qd, qh, qi,
                   q_spec, q_spec, q_spec, q_spec, q_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((nq,), jnp.int32),
+        name="wedge_check",
         interpret=interpret,
     )(keys_d, keys_h, keys_i, lo, hi, qd, qh, qi)

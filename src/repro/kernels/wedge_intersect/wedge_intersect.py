@@ -95,5 +95,6 @@ def wedge_intersect_pallas(keys_d, keys_h, keys_i, e, row_d, row_h, row_i,
         out_specs=[out, out],
         out_shape=(jax.ShapeDtypeStruct((B, L), jnp.int32),
                    jax.ShapeDtypeStruct((B, L), keys_i.dtype)),
+        name="wedge_intersect",
         interpret=interpret,
     )(keys_d, keys_h, keys_i, e, row_d, row_h, row_i, ln)

@@ -419,8 +419,7 @@ class SurveyService:
             # with on_overflow="raise" the epoch fails loudly (surfaced by
             # IngestPipeline on the next flush/submit) instead of
             # persistently corrupting every later resident answer
-            engine._exactness_guard(
-                cfg_d, jax.tree.map(float, jax.device_get(dstats)))
+            engine._exactness_guard(cfg_d, engine.sum_stats(dstats))
             new_state = (self._resident.merge_epochs(snap.resident_state,
                                                      merged)
                          if snap.resident_state is not None else merged)

@@ -24,6 +24,7 @@ __all__ = [
     "bucket_cap",
     "bucket_caps",
     "enable_compile_cache",
+    "span",
 ]
 
 
@@ -172,3 +173,14 @@ def enable_compile_cache() -> Path:
         jax.config.update("jax_compilation_cache_dir",
                           str(CHECKOUT_COMPILE_CACHE))
     return CHECKOUT_COMPILE_CACHE
+
+
+def span(name: str):
+    """``with span(name):`` marks a host phase of the program as the span
+    ``repro.<name>`` in the profiler's trace (a
+    ``jax.profiler.TraceAnnotation``, on the device trace's clock). Spans
+    opened inside it nest under it on the thread. With no profiler
+    running it costs one inactive check."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(f"repro.{name}")

@@ -23,9 +23,10 @@ folds the survey callback with all six metadata items local (Sec. 4.2/4.3).
 
 Pull superstep: shard s requests `Adj₊ᵐ(q)` once per (shard, q) for targets
 whose row is cheaper to move than the wedge candidates (the paper's
-per-pair decision), receives padded rows, searches each local suffix
-wedge's key in the pulled row (the push lane's keyed lower bound, tiled
-by wedge rank within a staging budget) and folds the survey locally.
+per-pair decision), receives padded rows, finds each local suffix
+wedge's closing vertex in the pulled row (a compare of the whole row where
+rows are narrow, else the push lane's keyed lower bound; tiled by wedge
+rank within a staging budget) and folds the survey locally.
 
 Hub superstep (two-tier exchange, after Arifuzzaman et al.'s heavy-vertex
 split): wedges whose center q has degree ≥ the plan's ``hub_theta`` never
@@ -78,7 +79,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import kernels
 from repro.comm.exchange import Exchange, make_exchange
-from repro.core.dodgr import ShardedDODGr, meta_widths
+from repro.core.dodgr import PAD_D, ShardedDODGr, meta_widths
 from repro.core.surveys import (MetaSpec, Survey, TriangleBatch, expand_lanes,
                                 narrow_lanes, project_lanes)
 from repro.utils import ceil_div, span
@@ -209,6 +210,17 @@ def _lower_bound(nbr_d, nbr_h, nbr_i, lo, hi, qd, qh, qi, n_steps):
 
     lo, hi = jax.lax.fori_loop(0, n_steps, body, (lo, hi))
     return lo
+
+
+def _dense_row_search(rows, ln, key):
+    """Slot of ``key[t]`` in the row ``rows[t, :ln[t]]``, by comparing every
+    slot: ``(found, slot)``, with ``slot = ln`` where the row lacks it.
+    Vertex ids are unique within an adjacency row, so the id alone decides
+    the hit that the keyed :func:`_lower_bound` finds by its sort key."""
+    j = jnp.arange(rows.shape[-1], dtype=jnp.int32)
+    first = jnp.min(jnp.where(rows == key[:, None], j, rows.shape[-1]),
+                    axis=-1)
+    return first < ln, jnp.minimum(first, ln)
 
 
 def _stream_setup(gr: ShardedDODGr, weight_mask=None):
@@ -638,19 +650,45 @@ def _pull_wire(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
 # entries fit this budget — the device-memory twin of the planner's
 # ~4 MiB reply-window bound (pushpull._autotune_pull_q_cap).
 PULL_STAGE_BYTES = 64 << 20
-# int32-sized words XLA keeps live per staged wedge besides its metadata
-# lanes (ranks, edge and row indices, search keys, bounds and probes,
-# hit masks, the p/q/r ids), sized against compiled memory_analysis() on
-# TPU v5e (tests/test_tpu_compile.py holds a program to the budget)
+# int32-sized words XLA keeps live per staged wedge besides its [T, w]
+# lane blocks (ranks, edge and row indices, bounds, hit masks, the p/q/r
+# ids), sized against compiled memory_analysis() on TPU v5e
+# (tests/test_tpu_compile.py holds two programs to the budget)
 _PULL_STAGE_WORDS = 64
 
 
-def pull_tile_wedges(S_ax: int, meta_words: int, window_max: int) -> int:
+# Reply rows of at most this many slots are searched densely: each wedge
+# gathers its whole pulled row and compares every slot with the closing
+# vertex. Timed alone on a TPU v5e, the compare beats the keyed binary
+# search at every width from 146 to 3,654 slots (3.7-6x at a common tile
+# of 16,384 wedges). Up to 1,024 slots XLA fuses the row gather into the
+# compare; from 2,048 it keeps the [T, Lr] block live, and the search
+# alone takes 65 MB at 2,048 and 122 MB at 3,654 slots at the tile that
+# pull_tile_wedges gives them. Wider rows (stable-order serve plans pad
+# them to d_plus_max) keep the binary search, within the budget
+DENSE_SEARCH_MAX_ROW = 1024
+# the TPU's lane width: a staged [T, w] block holds w rounded up to it
+_LANES = 128
+
+
+def pull_search(use_pallas: bool, row_cap: int) -> str:
+    """How the pull lane finds a wedge's closing vertex in its pulled row of
+    ``row_cap`` slots: ``"pallas"`` (the ``wedge_check`` kernel),
+    ``"dense"`` (:func:`_dense_row_search`) or ``"binary"``
+    (:func:`_lower_bound`). A static property of the plan, which the
+    planner's report carries as ``VolumeReport.pull_search``."""
+    if use_pallas:
+        return "pallas"
+    return "dense" if row_cap <= DENSE_SEARCH_MAX_ROW else "binary"
+
+
+def pull_tile_wedges(S_ax: int, extra_words: int, window_max: int) -> int:
     """Wedge slots per staged pull tile for ``S_ax`` stacked shards whose
-    batch carries ``meta_words`` metadata words per triangle — never more
+    wedges each stage ``extra_words`` words besides the fixed ones (the
+    triangle's metadata, the dense search's gathered row) — never more
     than the ``window_max`` wedges one window can hold."""
     return max(1, min(window_max, PULL_STAGE_BYTES
-                      // (4 * S_ax * (_PULL_STAGE_WORDS + meta_words))))
+                      // (4 * S_ax * (_PULL_STAGE_WORDS + extra_words))))
 
 
 def _pull_compute(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
@@ -677,6 +715,7 @@ def _pull_compute(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
     S_ax = gr.row_ptr.shape[0]
     ecap = cfg.pull_edge_cap
     Lr = cfg.pull_row_cap if cfg.pull_row_cap else gr.d_plus_max
+    search = pull_search(cfg.use_pallas, Lr)
     n_steps = max(1, int(np.ceil(np.log2(max(2, Lr)))) + 1)
     out_cap = exch.out_cap
 
@@ -687,11 +726,15 @@ def _pull_compute(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
     epq_f_l = narrow_lanes(gr.emeta_f, spec.e_pq_f)
     epr_i_l = narrow_lanes(gr.emeta_i, spec.e_pr_i)
     epr_f_l = narrow_lanes(gr.emeta_f, spec.e_pr_f)
-    meta_words = sum(x.shape[-1] for x in (
+    # each [T, w] lane block of the staged batch takes whole 128-lane
+    # tiles on the TPU: the metadata fields, and the dense search's row
+    lanes = lambda w: -(-w // _LANES) * _LANES
+    meta_words = sum(lanes(x.shape[-1]) for x in (
         vp_i_l, vp_f_l, epq_i_l, epq_f_l, epr_i_l, epr_f_l, rep["vq_i"],
         rep["vq_f"], rep["r_ti"], rep["r_tf"], rep["r_ei"], rep["r_ef"]))
+    row_words = lanes(Lr) if search == "dense" else 0
     # a suffix holds at most d_plus_max - 1 wedges
-    T = pull_tile_wedges(S_ax, meta_words,
+    T = pull_tile_wedges(S_ax, meta_words + row_words,
                          S * ecap * max(1, gr.d_plus_max - 1))
     # reply rows flattened [out_cap·Lr]: row ``ridx`` spans
     # [ridx·Lr, ridx·Lr + ln)
@@ -700,6 +743,17 @@ def _pull_compute(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
                                       "r_ei", "r_ef")}
     if cfg.delta:
         rows["r_new"] = flat(rep["r_new"])
+    if search == "dense":
+        # the dense search reads neither sort key, but the planner prices
+        # every reply slot with both and the mesh HLO reconciliation holds
+        # the wire to the plan: a test true of every row keeps both key
+        # lanes on the wire. It rests on every reply degree being >= 0: a
+        # real degree, PAD_D or BIG_I32.
+        # TODO(PERF.md §7 item 16): price only the lanes the requester
+        # reads, let the reconciliation follow that plan, drop this test
+        assert PAD_D >= 0 and int(BIG_I32) >= 0
+        rep = dict(rep, ok=rep["ok"] & ((rep["r_d"][..., 0] >= 0)
+                                        | (rep["r_h"][..., 0] == 0)))
 
     # jnp (not np) coercion: a mesh local view hands traced map rows
     pcap_d = jnp.asarray(exch.caps, jnp.int32)              # [S, S]
@@ -761,24 +815,29 @@ def _pull_compute(gr: ShardedDODGr, ps, t, cfg: EngineConfig,
         # the key search, the hit test and the gathers at the found slot
         with jax.named_scope("engine/pull/search"):
             ci = nbr[r_pos]
+            ln = rp["ln"][ridx]
             lo = ridx * Lr
-            hi = lo + rp["ln"][ridx]
-            if cfg.use_pallas:
-                pos = wc_ops.wedge_check(
-                    rows["r_d"], rows["r_h"], rows["r_nbr"], lo, hi,
-                    nbr_d[r_pos], nbr_h[r_pos], ci,
-                    interpret=not kernels.compiled())
+            if search == "dense":
+                found, slot = _dense_row_search(rp["r_nbr"][ridx], ln, ci)
+                pos_c = jnp.clip(lo + slot, 0, out_cap * Lr - 1)
             else:
-                pos = _lower_bound(rows["r_d"], rows["r_h"], rows["r_nbr"],
-                                   lo, hi, nbr_d[r_pos], nbr_h[r_pos], ci,
-                                   n_steps)
-            pos_c = jnp.clip(pos, 0, out_cap * Lr - 1)
+                hi = lo + ln
+                if search == "pallas":
+                    pos = wc_ops.wedge_check(
+                        rows["r_d"], rows["r_h"], rows["r_nbr"], lo, hi,
+                        nbr_d[r_pos], nbr_h[r_pos], ci,
+                        interpret=not kernels.compiled())
+                else:
+                    pos = _lower_bound(rows["r_d"], rows["r_h"],
+                                       rows["r_nbr"], lo, hi, nbr_d[r_pos],
+                                       nbr_h[r_pos], ci, n_steps)
+                pos_c = jnp.clip(pos, 0, out_cap * Lr - 1)
+                found = (pos < hi) & (rows["r_nbr"][pos_c] == ci)
             # the reply header's ok word (the owner's view of request
             # validity) rides back with the rows; AND-ing it in is a no-op
             # on every slot the requester's own maps admit, and keeps the
             # planned header word live on the wire
-            hit = (ok & rp["ok"][ridx] & (pos < hi)
-                   & (rows["r_nbr"][pos_c] == ci))
+            hit = ok & rp["ok"][ridx] & found
             if cfg.delta:
                 hit &= nbr_new[e] | nbr_new[r_pos] | rows["r_new"][pos_c]
             lp = jnp.clip(edge_src[e] // S, 0, n_loc - 1)

@@ -47,7 +47,7 @@ import numpy as np
 from repro.comm.exchange import TRANSPORTS
 from repro.core.dodgr import (delta_gen_mask, hub_widths, meta_widths,
                               orient_edges, sparsify_edges)
-from repro.core.engine import EngineConfig
+from repro.core.engine import EngineConfig, pull_search
 from repro.core.surveys import MetaSpec, Survey
 from repro.graphs.csr import DeltaGraph, HostGraph
 from repro.utils import bucket_cap, bucket_caps, bucket_floor, ceil_div, span
@@ -103,6 +103,9 @@ class VolumeReport:
     #                                  passed pull_q_cap=None)
     pull_row_cap: int = 0            # reply-row padding = max d₊ over pulled
     #                                  groups (hub delegation shrinks it)
+    pull_search: str = ""            # how the requester finds each wedge's
+    #                                  closing vertex in its pulled row:
+    #                                  engine.pull_search ("" = no pulls)
     # --- transport + hub delegation (two-tier exchange) ---
     transport: str = "dense"
     hub_theta: int = 0               # chosen degree threshold (0 = no hubs)
@@ -895,6 +898,8 @@ def plan_engine(
                 epoch=epoch,
                 pull_q_cap=pull_q_cap,
                 pull_row_cap=pull_row_cap,
+                pull_search=(pull_search(use_pallas, pull_row_cap)
+                             if n_pull_steps else ""),
                 transport=transport,
                 hub_theta=theta,
                 n_hubs=n_hubs,

@@ -64,6 +64,7 @@ def test_service_phase(smoke, graph):
 @pytest.mark.skipif(jax.device_count() < 4,
                     reason="needs 4 devices (conftest.py forces them "
                            "unless jax initialized first)")
+@pytest.mark.usefixtures("window_bound_pull_tiles")
 def test_mesh_phase(smoke, graph):
     out = smoke.phase_mesh(graph, S=4)
     assert [(c["survey"], c["caps"]) for c in out["cases"]] == [

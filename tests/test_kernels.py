@@ -207,12 +207,14 @@ else:
 
 
 @pytest.mark.parametrize("mode", ["pushpull"])
-def test_engine_fused_pull_kernel_bitwise(mode):
+def test_engine_fused_pull_kernel_bitwise(mode, monkeypatch):
     """Engine-level parity: the pull lane's keyed search through the
     wedge_check kernel == the jnp lower bound, result and stats, bit for
-    bit."""
+    bit. The jnp run is held to the lower bound (the dense row search
+    takes rows this narrow)."""
     import dataclasses
 
+    from repro.core import engine
     from repro.core.dodgr import shard_dodgr
     from repro.core.engine import survey_push_pull
     from repro.core.pushpull import plan_engine
@@ -224,6 +226,7 @@ def test_engine_fused_pull_kernel_bitwise(mode):
     cfg, _ = plan_engine(g, 4, mode=mode, push_cap=64, pull_q_cap=4,
                          use_pallas=True)
     res_k, st_k = survey_push_pull(gr, TriangleCount(), cfg)
+    monkeypatch.setattr(engine, "DENSE_SEARCH_MAX_ROW", 0)
     res_j, st_j = survey_push_pull(
         gr, TriangleCount(), dataclasses.replace(cfg, use_pallas=False))
     assert st_k["wedges_pulled"] > 0

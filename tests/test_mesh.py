@@ -59,6 +59,7 @@ def _run_pair(g, survey, mode, mesh, hub_theta=0, **kw):
     return out
 
 
+@pytest.mark.usefixtures("window_bound_pull_tiles")
 @pytest.mark.parametrize("mode", ["push", "pushpull"])
 @pytest.mark.parametrize("name,survey", builtin_surveys(n=96),
                          ids=[n for n, _ in builtin_surveys(n=96)])
@@ -70,6 +71,7 @@ def test_mesh_bitwise_identical_per_survey(graph, mesh, name, survey, mode):
     assert _tree_equal(st_m, st_r), name
 
 
+@pytest.mark.usefixtures("window_bound_pull_tiles")
 @pytest.mark.parametrize("mode", ["push", "pushpull"])
 def test_mesh_matches_dense_via_uniform_caps(graph, mesh, mode):
     """A uniform-cap mesh run (the literal all_to_all path) reproduces the
@@ -86,6 +88,7 @@ def test_mesh_matches_dense_via_uniform_caps(graph, mesh, mode):
     assert _tree_equal(st_m, st_d)
 
 
+@pytest.mark.usefixtures("window_bound_pull_tiles")
 @pytest.mark.parametrize("mode", ["push", "pushpull"])
 def test_mesh_hub_cell_bitwise(graph, mesh, mode):
     """Hub delegation (θ cell): replicated hub tables under shard_map ==

@@ -19,7 +19,8 @@ import pytest
 
 from repro import kernels
 from repro.core.dodgr import shard_dodgr
-from repro.core.engine import PULL_STAGE_BYTES, make_survey_fn
+from repro.core.engine import (DENSE_SEARCH_MAX_ROW, PULL_STAGE_BYTES,
+                               make_survey_fn)
 from repro.core.pushpull import plan_engine
 from repro.core.surveys import (DegreeTriples, Enumerate, LabelTripleSet,
                                 TriangleBatch, TriangleCount)
@@ -140,4 +141,19 @@ def test_pushpull_temporaries_within_stage_budget(chip):
     gr, _ = shard_dodgr(g, 4, hub_theta=cfg.hub_theta, orient="degree")
     ab = jax.tree.map(lambda x: chip(x.shape, x.dtype), gr)
     c = _compile(make_survey_fn(TriangleCount(), cfg), ab)
+    assert c.memory_analysis().temp_size_in_bytes < PULL_STAGE_BYTES
+
+
+def test_pushpull_dense_search_with_labels_within_stage_budget(chip):
+    """The same graph with a vertex label lane, surveyed by LabelTripleSet:
+    the dense pull search gathers whole reply rows, and the staged tile
+    still keeps the compiled program's temporaries within the budget."""
+    g = rmat(12, 16, seed=0, spec=MetaSpec(v_int=("label",)))
+    sv = LabelTripleSet(v_label_col=0)
+    cfg, _ = plan_engine(g, 4, sv, mode="pushpull")
+    assert cfg.n_pull_steps > 0
+    assert 0 < cfg.pull_row_cap <= DENSE_SEARCH_MAX_ROW
+    gr, _ = shard_dodgr(g, 4, hub_theta=cfg.hub_theta, orient="degree")
+    ab = jax.tree.map(lambda x: chip(x.shape, x.dtype), gr)
+    c = _compile(make_survey_fn(sv, cfg), ab)
     assert c.memory_analysis().temp_size_in_bytes < PULL_STAGE_BYTES
